@@ -6,14 +6,19 @@ hosted context is lost).  A fresh opportunistic joiner takes over: the
 scheduler re-stages the context there once and completes the run — the
 paper's Challenge #1 handled by design, live.
 
-  PYTHONPATH=src python examples/serve_opportunistic.py
+Both workers run on this process's device and are described by its
+catalog entry; a CPU run names the device it stands in for:
+
+  JAX_PLATFORMS=cpu PYTHONPATH=src python examples/serve_opportunistic.py \
+      --device "NVIDIA A10"
 """
+import argparse
 import os
 import sys
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
-from repro.cluster import LiveExecutor, Scheduler, Worker
-from repro.cluster.hardware import GPU_CATALOG
+from repro.cluster import (LiveExecutor, Scheduler, Worker,
+                           local_device_model)
 from repro.cluster.scheduler import Task
 from repro.configs import get_smoke_config
 from repro.core import PERVASIVE
@@ -21,14 +26,19 @@ from repro.data import accuracy, claim_batches, generate_claims
 from repro.inference import build_context_recipe, infer_claims
 
 
-def main():
+def main(argv=None):
+    ap = argparse.ArgumentParser(description=__doc__)
+    ap.add_argument("--device", default=None, metavar="NAME",
+                    help="catalog entry describing the workers' device; "
+                         "default: the entry for this process's accelerator")
+    device = local_device_model(ap.parse_args(argv).device)
     cfg = get_smoke_config("smollm2-1.7b")
     claims = generate_claims(48, seed=3)
     recipe = build_context_recipe(cfg, "zero_shot")
 
     sched = Scheduler()
     key = sched.register_context(recipe)
-    w0 = Worker(GPU_CATALOG["NVIDIA A10"])
+    w0 = Worker(device)
     sched.add_worker(w0)
     for b in claim_batches(claims, 8):
         sched.submit(Task(key, len(b), PERVASIVE, payload=b))
@@ -41,7 +51,7 @@ def main():
         if (not evicted["done"]
                 and sched.completed_inferences >= len(claims) // 3):
             requeued = sched.on_evict(w0.worker_id)
-            joiner = Worker(GPU_CATALOG["NVIDIA TITAN X (Pascal)"])
+            joiner = Worker(device)
             sched.add_worker(joiner)
             evicted["done"] = True
             print(f"[pool] {w0.worker_id} EVICTED "

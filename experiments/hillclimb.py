@@ -5,8 +5,6 @@ has an op-level profile to reason from.
   PYTHONPATH=src python experiments/hillclimb.py llama3-405b train_4k
 """
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 import re
 import sys
 
@@ -93,4 +91,6 @@ def main():
 
 
 if __name__ == "__main__":
+    # 512 placeholder host devices; set before the first backend use
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
     main()

@@ -33,8 +33,8 @@ surface in run summaries via :func:`zone_byte_summary` /
 from .events import EventLoop, Timer
 from .hardware import (DECODE_FIXED_FRAC, GPU_CATALOG, TPU_CATALOG,
                        PAPER_CLUSTER, ClusterSpec, DeviceModel,
-                       cluster_sample, paper_20gpu_pool, pool_rate,
-                       REF_ACTIVE_PARAMS)
+                       cluster_sample, local_device_model,
+                       paper_20gpu_pool, pool_rate, REF_ACTIVE_PARAMS)
 from .worker import Worker
 from .scheduler import (Assignment, DECODE, PREFILL, Request,
                         RequestRecord, Scheduler, Task, TaskRecord)
@@ -66,7 +66,8 @@ __all__ = [
     "PAPER_CLUSTER", "REF_ACTIVE_PARAMS", "REJECTED", "Request",
     "RequestRecord", "SLOClass", "Scheduler", "SimExecutor", "Storm",
     "TIMED_OUT", "TPU_CATALOG", "Task", "TaskRecord",
-    "Timer", "Worker", "cluster_sample", "format_gateway", "make_sim",
+    "Timer", "Worker", "cluster_sample", "format_gateway",
+    "local_device_model", "make_sim",
     "opportunistic_supply", "paper_20gpu_pool", "pool_rate",
     "spill_aware_evict_priority", "storm_schedule", "traces",
     "ProgressMonitor", "Snapshot", "class_latency_summary",

@@ -962,6 +962,7 @@ class LiveExecutor(_PlanOpExecution):
         self._open: List[Tuple[Worker, str]] = []
         # (worker_id, key) -> decoder kv_resume_bytes_total last metered
         self._kv_resume_seen: Dict[Tuple[str, str], int] = {}
+        self.staging_s = 0.0                # wall seconds materialising
         self._t0 = time.perf_counter()
 
     def now(self) -> float:
@@ -985,12 +986,17 @@ class LiveExecutor(_PlanOpExecution):
         self.execute_plan(plan)
         return len(plan.acquire_ops())
 
+    def _make_ready(self, lib) -> None:
+        """Materialise ``lib`` if it is not hosted yet (weights, compile),
+        metering the wall time into ``staging_s``."""
+        if not lib.ready:
+            self.staging_s += lib.materialize().total_s
+
     # -- shared plan-op path: live staging really runs the loaders ---------
     def _materialize_op(self, op, w: Worker, recipe,
                         attempt: int = 0) -> None:
         lib = w.library_for(recipe)
-        if not lib.ready:
-            lib.materialize()
+        self._make_ready(lib)
         w.staging = False
         # live loaders do not move the plan's network bytes (everything is
         # on this container); account the op as priced
@@ -1001,8 +1007,7 @@ class LiveExecutor(_PlanOpExecution):
         req, w = a.request, a.worker
         recipe = self.sched.registry.recipes[req.recipe_key]
         lib = w.library_for(recipe)
-        if not lib.ready:
-            lib.materialize()
+        self._make_ready(lib)
         self.sched.on_staged(a)
         out = lib.invoke(self.fns[req.recipe_key], req.payload)
         self.results[req.request_id] = out
@@ -1035,8 +1040,7 @@ class LiveExecutor(_PlanOpExecution):
             if not a.join:              # founding member: open the batch
                 lib = w.library_for(
                     self.sched.registry.recipes[req.recipe_key])
-                if not lib.ready:
-                    lib.materialize()
+                self._make_ready(lib)
                 self.sched.on_staged(a)
                 self._open.append((w, req.recipe_key))
             if a.kv_ship is not None:
@@ -1055,8 +1059,7 @@ class LiveExecutor(_PlanOpExecution):
         t_start = self.now()
         recipe = self.sched.registry.recipes[req.recipe_key]
         lib = w.library_for(recipe)
-        if not lib.ready:
-            lib.materialize()
+        self._make_ready(lib)
         self.sched.on_staged(a)
         prefill = getattr(self.step_fns.get(req.recipe_key), "prefill",
                           None)

@@ -131,6 +131,35 @@ TPU_CATALOG: Dict[str, DeviceModel] = {m.name: m for m in [
                 compile_base_s=40, tflops=918.0),
 ]}
 
+# ``jax.Device.device_kind`` of each catalogued TPU generation.
+DEVICE_KINDS: Dict[str, str] = {
+    "TPU v4": "TPU v4",
+    "TPU v5 lite": "TPU v5e",
+    "TPU v5": "TPU v5p",
+    "TPU v6 lite": "TPU v6e",
+}
+
+
+def local_device_model(name: Optional[str] = None) -> DeviceModel:
+    """Catalog entry of the device live workers run on.
+
+    ``name`` picks an entry explicitly (a CPU run has no entry of its own,
+    so it names the device it stands in for); otherwise the entry is the
+    one for ``jax.devices()[0].device_kind``, and a kind missing from
+    :data:`DEVICE_KINDS` is an error."""
+    catalog = {**GPU_CATALOG, **TPU_CATALOG}
+    if name is None:
+        import jax
+        kind = jax.devices()[0].device_kind
+        if kind not in DEVICE_KINDS:
+            raise ValueError(
+                f"device kind {kind!r} has no catalog entry; name one "
+                f"explicitly (one of {sorted(catalog)})")
+        name = DEVICE_KINDS[kind]
+    if name not in catalog:
+        raise KeyError(f"unknown device {name!r}; one of {sorted(catalog)}")
+    return catalog[name]
+
 
 @dataclass(frozen=True)
 class ClusterSpec:

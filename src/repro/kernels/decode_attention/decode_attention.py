@@ -22,8 +22,10 @@ BlockSpec index_map can pick each program's physical page —
 Refcounted shared-prefix pages are thus gathered per-row at DMA time with
 zero data duplication (vLLM's PagedAttention access pattern).
 
-G is padded to the 8-sublane minimum by the wrapper when n_heads == n_kv
-(MHA decode).
+Blocks: the dense kernel reads ``bk`` = 256 ring slots per step, or the
+whole ring when 256 does not divide it (a block equal to the array dim is
+always a legal TPU tile); the paged kernel reads one whole page.  The q/out
+block is the full (G, hd) group, so MHA (G == 1) runs unpadded.
 """
 from __future__ import annotations
 
@@ -92,7 +94,8 @@ def decode_attention_pallas(q, k, v, n_valid, *, softcap: float = 0.0,
     if scale is None:
         scale = 1.0 / math.sqrt(hd)
     bk = min(bk, T)
-    assert T % bk == 0, (T, bk)
+    if T % bk:
+        bk = T                                         # whole ring, one block
     n_kv_blocks = T // bk
 
     qg = q.reshape(B, K, G, hd)                        # group q-heads by kv head

@@ -1,48 +1,33 @@
-"""Jit'd wrappers for decode attention (dense + paged) with platform dispatch."""
+"""Decode attention (dense + paged): the Pallas kernel in a program compiled
+for TPU, the pure-jnp reference elsewhere (see ``kernels/platform.py``)."""
 from __future__ import annotations
 
-import jax
+import functools
 
+from ..platform import tpu_kernel_else_ref
 from .decode_attention import (decode_attention_paged_pallas,
                                decode_attention_pallas)
 from .ref import decode_attention_paged_ref, decode_attention_ref
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 def decode_attention(q, k, v, n_valid, *, softcap: float = 0.0,
-                     scale: float | None = None,
-                     use_pallas: bool | None = None,
-                     interpret: bool = False):
+                     scale: float | None = None):
     """q: (B,1,H,hd); k,v ring cache (B,T,K,hd); n_valid int32 scalar or
     (B,) vector (per-row valid length — slot-pool decode)."""
-    if use_pallas is None:
-        use_pallas = _on_tpu()
-    T = k.shape[1]
-    if use_pallas and q.shape[1] == 1 and T % min(256, T) == 0:
-        return decode_attention_pallas(q, k, v, n_valid, softcap=softcap,
-                                       scale=scale,
-                                       interpret=interpret or not _on_tpu())
-    return decode_attention_ref(q, k, v, n_valid, softcap=softcap, scale=scale)
+    kw = dict(softcap=softcap, scale=scale)
+    return tpu_kernel_else_ref(
+        functools.partial(decode_attention_pallas, **kw),
+        functools.partial(decode_attention_ref, **kw), q, k, v, n_valid)
 
 
 def decode_attention_paged(q, k_pages, v_pages, page_table, n_valid, *,
-                           softcap: float = 0.0, scale: float | None = None,
-                           use_pallas: bool | None = None,
-                           interpret: bool = False):
+                           softcap: float = 0.0, scale: float | None = None):
     """Paged decode attention: q (B,1,H,hd); k_pages/v_pages physical pools
     (n_pages,P,K,hd); page_table (B,max_pages) int32 (clamped >= 0, unmapped
     entries alias the trash page and sit past n_valid); n_valid int32 scalar
     or (B,) per-row valid length over the LOGICAL ring (max_pages*P slots)."""
-    if use_pallas is None:
-        use_pallas = _on_tpu()
-    P = k_pages.shape[1]
-    # TPU lane constraint: one KV block per page, so the page must tile
-    if use_pallas and q.shape[1] == 1 and P % min(128, P) == 0:
-        return decode_attention_paged_pallas(
-            q, k_pages, v_pages, page_table, n_valid, softcap=softcap,
-            scale=scale, interpret=interpret or not _on_tpu())
-    return decode_attention_paged_ref(q, k_pages, v_pages, page_table,
-                                      n_valid, softcap=softcap, scale=scale)
+    kw = dict(softcap=softcap, scale=scale)
+    return tpu_kernel_else_ref(
+        functools.partial(decode_attention_paged_pallas, **kw),
+        functools.partial(decode_attention_paged_ref, **kw),
+        q, k_pages, v_pages, page_table, n_valid)

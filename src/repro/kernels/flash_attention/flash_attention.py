@@ -4,8 +4,10 @@ Online-softmax flash attention tiled for VMEM/MXU:
 
 - grid = (B, H, S/bq, T/bk); the KV-block axis is the innermost sequential
   dimension, with fp32 scratch accumulators (m, l, acc) carried across it.
-- q/k/v tiles are MXU-aligned: bq = bk = 128, head_dim padded to a multiple
-  of 128 by the wrapper (ops.py) when needed.
+- q/k/v tiles are bq = bk = 128 rows; a length that 128 does not divide
+  is taken as one whole block (a block equal to the array dim is always a
+  legal TPU tile).  head_dim is never padded: the tile's lane dim is the
+  full head_dim.
 - GQA is expressed in the BlockSpec index maps: the k/v tile for q-head h is
   kv-head h // group_size — no repeated KV is ever materialised in VMEM.
 - causal + sliding-window blocks that are fully masked are skipped via
@@ -88,16 +90,15 @@ def flash_attention_pallas(q, k, v, *, causal: bool = True, window: int = 0,
                            bq: int = 128, bk: int = 128,
                            interpret: bool = False):
     """q: (B,S,H,hd); k: (B,T,K,hd); v: (B,T,K,hd_v) — hd_v may differ (MLA).
-    Requires S % bq == 0 and T % bk == 0."""
+    A length that ``bq``/``bk`` does not divide is tiled as one block."""
     B, S, H, hd = q.shape
     T, K = k.shape[1], k.shape[2]
     hd_v = v.shape[-1]
     G = H // K
     if scale is None:
         scale = 1.0 / math.sqrt(hd)
-    bq = min(bq, S)
-    bk = min(bk, T)
-    assert S % bq == 0 and T % bk == 0, (S, T, bq, bk)
+    bq = S if S % min(bq, S) else min(bq, S)
+    bk = T if T % min(bk, T) else min(bk, T)
     n_kv_blocks = T // bk
 
     # layout: (B, H, S, hd) so the lane dim is hd and sublane is seq

@@ -1,37 +1,27 @@
-"""Jit'd wrapper for flash attention with platform dispatch.
+"""Flash attention: the Pallas kernel in a program compiled for TPU, the
+pure-jnp reference elsewhere (see ``kernels/platform.py``).
 
-TPU -> Pallas kernel; CPU (tests, dry-run) -> pure-jnp reference.  The
-dry-run intentionally lowers the reference path: ``cost_analysis()`` needs
-the XLA-visible FLOPs, and custom-call kernels are opaque to it.
+The dry-run compiles for CPU and so lowers the reference path on purpose:
+``cost_analysis()`` needs the XLA-visible FLOPs, and custom-call kernels are
+opaque to it.
 """
 from __future__ import annotations
 
-import jax
+import functools
 
+from ..platform import tpu_kernel_else_ref
 from .flash_attention import flash_attention_pallas
 from .ref import flash_attention_ref
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
-                    softcap: float = 0.0, scale: float | None = None,
-                    use_pallas: bool | None = None,
-                    interpret: bool = False):
-    """Dispatching entry point used by the model code.
+                    softcap: float = 0.0, scale: float | None = None):
+    """Entry point used by the model code.
 
     q: (B,S,H,hd); k,v: (B,T,K,hd); H = G*K. Sliding ``window`` and
     ``softcap`` are static. Returns (B,S,H,hd) in q.dtype.
     """
-    if use_pallas is None:
-        use_pallas = _on_tpu()
-    S, T = q.shape[1], k.shape[1]
-    aligned = S % min(128, S) == 0 and T % min(128, T) == 0
-    if use_pallas and aligned:
-        return flash_attention_pallas(q, k, v, causal=causal, window=window,
-                                      softcap=softcap, scale=scale,
-                                      interpret=interpret or not _on_tpu())
-    return flash_attention_ref(q, k, v, causal=causal, window=window,
-                               softcap=softcap, scale=scale)
+    kw = dict(causal=causal, window=window, softcap=softcap, scale=scale)
+    return tpu_kernel_else_ref(functools.partial(flash_attention_pallas, **kw),
+                               functools.partial(flash_attention_ref, **kw),
+                               q, k, v)

@@ -1,26 +1,18 @@
-"""Jit'd wrapper for the selective-SSM scan with platform dispatch."""
+"""Selective-SSM scan: the Pallas kernel in a program compiled for TPU, the
+pure-jnp reference elsewhere (see ``kernels/platform.py``)."""
 from __future__ import annotations
 
-import jax
+import functools
 
+from ..platform import tpu_kernel_else_ref
 from .ref import ssm_scan_ref, ssm_step_ref
 from .ssm_scan import ssm_scan_pallas
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
-def ssm_scan(x, dt, A, B, C, D, *, chunk: int = 128,
-             use_pallas: bool | None = None, interpret: bool = False):
-    """Dispatching entry point. Shapes as in ref.py."""
-    if use_pallas is None:
-        use_pallas = _on_tpu()
-    L = x.shape[1]
-    if use_pallas and L % min(chunk, L) == 0:
-        return ssm_scan_pallas(x, dt, A, B, C, D, chunk=chunk,
-                               interpret=interpret or not _on_tpu())
-    return ssm_scan_ref(x, dt, A, B, C, D)
+def ssm_scan(x, dt, A, B, C, D, *, chunk: int = 128):
+    """Shapes as in ref.py."""
+    return tpu_kernel_else_ref(functools.partial(ssm_scan_pallas, chunk=chunk),
+                               ssm_scan_ref, x, dt, A, B, C, D)
 
 
 ssm_step = ssm_step_ref  # single-token decode step (pure jnp everywhere)

@@ -24,10 +24,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams; support both.
-_COMPILER_PARAMS = getattr(pltpu, "CompilerParams", None) \
-    or pltpu.TPUCompilerParams
-
 
 def _kernel(x_ref, dt_ref, A_ref, B_ref, C_ref, D_ref, y_ref, hout_ref,
             h_scr, *, chunk: int, n_chunks: int):
@@ -63,11 +59,11 @@ def ssm_scan_pallas(x, dt, A, B, C, D, *, chunk: int = 128,
                     interpret: bool = False):
     """x, dt: (Bt,L,DI); A: (DI,N); B, C: (Bt,L,N); D: (DI,).
 
-    Returns (y (Bt,L,DI), h_final (Bt,DI,N) fp32). L % chunk must be 0."""
+    Returns (y (Bt,L,DI), h_final (Bt,DI,N) fp32).  A length ``chunk`` does
+    not divide is scanned as one chunk."""
     Bt, L, DI = x.shape
     N = A.shape[1]
-    chunk = min(chunk, L)
-    assert L % chunk == 0, (L, chunk)
+    chunk = L if L % min(chunk, L) else min(chunk, L)
     n_chunks = L // chunk
     grid = (Bt, n_chunks)
     kern = functools.partial(_kernel, chunk=chunk, n_chunks=n_chunks)
@@ -91,7 +87,7 @@ def ssm_scan_pallas(x, dt, A, B, C, D, *, chunk: int = 128,
             jax.ShapeDtypeStruct((Bt, DI, N), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((DI, N), jnp.float32)],
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(x, dt, A, jnp.asarray(B), jnp.asarray(C), D.reshape(1, DI))

@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (arch × shape × mesh).
 
 This is the proof that the distribution config is coherent without real
@@ -17,6 +14,7 @@ Usage:
 """
 import argparse
 import json
+import os
 import re
 import sys
 import time
@@ -203,8 +201,6 @@ def dryrun_one(arch: str, shape_name: str, *, multi_pod: bool = False,
         compiled = lowered.compile()
         mem = compiled.memory_analysis()
         cost = compiled.cost_analysis() or {}
-        if isinstance(cost, (list, tuple)):  # older jax: per-program dicts
-            cost = cost[0] if cost else {}
         # collectives: exact — while bodies scaled by known_trip_count
         coll = collective_bytes_scaled(compiled.as_text())
         # flops: cost_analysis counts scan bodies once; correct by lowering
@@ -311,4 +307,6 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    # 512 placeholder host devices; set before the first backend use
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
     sys.exit(main())

@@ -42,8 +42,6 @@ def _body_metrics(fn, args, in_sh, parse_collectives) -> Dict[str, float]:
     lowered = jax.jit(fn, in_shardings=in_sh).lower(*args)
     compiled = lowered.compile()
     cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):    # older jax: list of per-program dicts
-        cost = cost[0] if cost else {}
     coll = parse_collectives(compiled.as_text())
     return {
         "flops": float(cost.get("flops", 0.0)),

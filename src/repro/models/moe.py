@@ -187,7 +187,7 @@ def moe_apply_a2a(p, cfg, x, *, mesh, data_axes, model_axis="model"):
     combined output is psum'd back to replicated.
     """
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     e = cfg.moe
     E = e.n_experts
@@ -285,7 +285,7 @@ def moe_apply_a2a(p, cfg, x, *, mesh, data_axes, model_axis="model"):
         body, mesh=mesh,
         in_specs=(x_spec, rep, w_e, w_e, w_e),
         out_specs=(x_spec, P(model_axis)),
-        check_rep=False,
+        check_vma=False,
     )(x, p["router"], p["we1"], p["we3"], p["we2"])
     if e.n_shared_experts:
         y = y + _shared_ffn(p, x)

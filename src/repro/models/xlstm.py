@@ -266,7 +266,7 @@ def _batch_local(apply_fn, p, cfg, x, return_state: bool):
     """
     from ..sharding import active_ctx
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     ctx = active_ctx()
     if ctx is None or cfg.parallel.tensor_parallel:
@@ -280,4 +280,4 @@ def _batch_local(apply_fn, p, cfg, x, return_state: bool):
         return apply_fn(p_, cfg, x_, return_state=return_state)
 
     return shard_map(inner, mesh=ctx.mesh, in_specs=(P(), spec),
-                     out_specs=out_specs, check_rep=False)(p, x)
+                     out_specs=out_specs, check_vma=False)(p, x)

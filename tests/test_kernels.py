@@ -1,4 +1,6 @@
 """Per-kernel shape/dtype sweeps: Pallas (interpret=True) vs jnp oracle."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -12,6 +14,8 @@ from repro.kernels.decode_attention.decode_attention import (
 from repro.kernels.decode_attention.ref import (decode_attention_paged_ref,
                                                 decode_attention_ref,
                                                 gather_pages_ref)
+from repro.kernels.decode_attention import ops as decode_ops
+from repro.kernels.ssm_scan import ops as ssm_ops
 from repro.kernels.ssm_scan.ssm_scan import ssm_scan_pallas
 from repro.kernels.ssm_scan.ref import ssm_scan_ref, ssm_step_ref
 
@@ -72,7 +76,7 @@ class TestFlashAttention:
                                    rtol=2e-5, atol=2e-5)
 
     def test_dispatch_unaligned_falls_back(self):
-        # odd lengths route to the reference path and still agree with it
+        # a CPU program lowers the reference at any length, odd ones too
         q, k, v = _qkv(jax.random.PRNGKey(4), 1, 100, 100, 2, 2, 64,
                        jnp.float32)
         out = flash_attention(q, k, v, causal=True)
@@ -288,3 +292,79 @@ class TestSSMScan:
                                    rtol=1e-5, atol=1e-5)
         np.testing.assert_allclose(np.asarray(h_scan), np.asarray(h),
                                    rtol=1e-5, atol=1e-5)
+
+
+class TestPlatformDispatch:
+    """ops.py stages the kernel for TPU programs and the reference for
+    every other platform; a length the preferred block does not divide
+    is tiled as one whole block instead of falling back."""
+
+    def test_flash_untiled_length_is_one_block(self):
+        q, k, v = _qkv(jax.random.PRNGKey(20), 1, 136, 136, 4, 2, 64,
+                       jnp.float32)
+        out = flash_attention_pallas(q, k, v, causal=True, interpret=True)
+        ref = flash_attention_ref(q, k, v, causal=True)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   rtol=2e-5, atol=2e-5)
+
+    def test_decode_untiled_ring_is_one_block(self):
+        q, k, v = _qkv(jax.random.PRNGKey(21), 2, 1, 384, 4, 2, 64,
+                       jnp.float32)
+        nv = jnp.asarray([300, 384], jnp.int32)
+        out = decode_attention_pallas(q, k, v, nv, interpret=True)
+        ref = decode_attention_ref(q, k, v, nv)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   rtol=2e-5, atol=2e-5)
+
+    def test_ssm_untiled_length_is_one_chunk(self):
+        ks = jax.random.split(jax.random.PRNGKey(22), 5)
+        Bt, L, DI, N = 1, 96, 64, 8
+        x = jax.random.normal(ks[0], (Bt, L, DI), jnp.float32)
+        dt = jax.random.normal(ks[1], (Bt, L, DI), jnp.float32) * 0.1
+        A = -jnp.abs(jax.random.normal(ks[2], (DI, N), jnp.float32)) - 0.1
+        B = jax.random.normal(ks[3], (Bt, L, N), jnp.float32)
+        C = jax.random.normal(ks[4], (Bt, L, N), jnp.float32)
+        D = jnp.ones((DI,), jnp.float32)
+        y, h = ssm_scan_pallas(x, dt, A, B, C, D, chunk=64, interpret=True)
+        y_ref, h_ref = ssm_scan_ref(x, dt, A, B, C, D)
+        np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref),
+                                   rtol=1e-4, atol=1e-4)
+        np.testing.assert_allclose(np.asarray(h), np.asarray(h_ref),
+                                   rtol=1e-4, atol=1e-4)
+
+    def test_cpu_program_lowers_reference(self):
+        q, k, v = _qkv(jax.random.PRNGKey(23), 1, 1, 256, 4, 2, 64,
+                       jnp.float32)
+        nv = jnp.asarray([100], jnp.int32)
+        text = jax.jit(decode_ops.decode_attention).lower(q, k, v,
+                                                          nv).as_text()
+        assert "tpu_custom_call" not in text
+        np.testing.assert_allclose(
+            np.asarray(decode_ops.decode_attention(q, k, v, nv)),
+            np.asarray(decode_attention_ref(q, k, v, nv)), rtol=1e-6,
+            atol=1e-6)
+
+    @pytest.mark.parametrize("op", ["flash", "ssm"])
+    def test_grad_is_reference_grad(self, op):
+        """Training differentiates through the dispatch: the gradient is
+        the reference's on every platform (the kernels have no bwd)."""
+        if op == "flash":
+            q, k, v = _qkv(jax.random.PRNGKey(24), 1, 128, 128, 4, 2, 64,
+                           jnp.float32)
+            got = jax.grad(lambda q: flash_attention(q, k, v).sum())(q)
+            want = jax.grad(lambda q: flash_attention_ref(q, k, v).sum())(q)
+        else:
+            ks = jax.random.split(jax.random.PRNGKey(25), 3)
+            x = jax.random.normal(ks[0], (1, 32, 16), jnp.float32)
+            dt = jax.random.normal(ks[1], (1, 32, 16), jnp.float32) * 0.1
+            A = -jnp.ones((16, 4), jnp.float32)
+            Bm = jax.random.normal(ks[2], (1, 32, 4), jnp.float32)
+            D = jnp.ones((16,), jnp.float32)
+
+            def loss(fn, x):
+                return fn(x, dt, A, Bm, Bm, D)[0].sum()
+
+            got = jax.grad(functools.partial(loss, ssm_ops.ssm_scan))(x)
+            want = jax.grad(functools.partial(loss, ssm_scan_ref))(x)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=1e-6, atol=1e-6)

@@ -1,0 +1,106 @@
+"""Compile-only guards for one TPU v5e chip at smollm2-1.7b's widths.
+
+Nothing runs: each program is compiled for a DESCRIBED v5e chip (the TPU
+compiler is installed even where no chip is attached), which is where
+tiling, VMEM and HBM limits are enforced.  The kernel choice follows the
+platform a program is compiled for, so each compiled program must contain
+its Pallas kernel (``tpu_custom_call``), and the full-width serving steps
+must fit the chip's 16 GB.
+
+The topology is described only inside a module fixture: describing it
+loads the TPU library, which one process at a time may hold.
+"""
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+import chip_smoke
+from repro.configs import get_config
+from repro.inference import PROMPT_LEN
+from repro.models import model as M
+
+HBM_BYTES = 16e9
+B = 16                        # slot-pool capacity the decoder grows to
+PAGE = 64
+MAX_LEN = PROMPT_LEN + 64     # the streaming decoder's ring
+MAX_PAGES = -(-MAX_LEN // PAGE)
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure means "cannot"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # entries compiled for a described chip cannot be read back here
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+
+
+@pytest.fixture(scope="module")
+def cfg():
+    return get_config("smollm2-1.7b")
+
+
+def _specs(tree, sharding):
+    return jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding),
+        tree)
+
+
+@pytest.mark.parametrize("name", ["dense decode", "paged decode",
+                                  "flash prefill"])
+def test_kernel_compiles_into_program(one_chip, cfg, name):
+    """The kernels at the shapes ``chip_smoke.py`` runs them on the chip."""
+    op, args = {n: (op, args) for n, op, _ref, args in chip_smoke.kernel_cases(
+        cfg, B, PROMPT_LEN, MAX_LEN, PAGE)}[name]
+    compiled = jax.jit(op).lower(*_specs(args, one_chip)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def _paged_state(cfg, sharding):
+    n_pages = 1 + B * MAX_PAGES
+    params = jax.eval_shape(lambda k: M.init_params(cfg, k),
+                            jax.random.PRNGKey(0))
+    cache = jax.eval_shape(functools.partial(
+        M.paged_cache_init, cfg, B, n_pages, PAGE, MAX_PAGES))
+    return _specs(params, sharding), _specs(cache, sharding)
+
+
+def _fits(compiled) -> None:
+    ma = compiled.memory_analysis()
+    used = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+            + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
+    assert used < HBM_BYTES, ma
+
+
+def test_full_width_decode_step(one_chip, cfg):
+    params, cache = _paged_state(cfg, one_chip)
+    toks = jax.ShapeDtypeStruct((B, 1), jnp.int32, sharding=one_chip)
+    mask = jax.ShapeDtypeStruct((B,), jnp.bool_, sharding=one_chip)
+    compiled = jax.jit(functools.partial(M.decode_step, cfg)).lower(
+        params, cache, toks, mask).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    _fits(compiled)
+
+
+def test_full_width_prefill_into_pages(one_chip, cfg):
+    params, cache = _paged_state(cfg, one_chip)
+    Bn = 8
+    S = jax.ShapeDtypeStruct
+    rows = S((Bn,), jnp.int32, sharding=one_chip)
+    batch = {"tokens": S((Bn, PROMPT_LEN), jnp.int32, sharding=one_chip)}
+    compiled = jax.jit(functools.partial(M.prefill_into_pages, cfg)).lower(
+        params, batch, cache, rows, rows, rows).compile()
+    _fits(compiled)
