@@ -1,4 +1,4 @@
-"""Benchmark driver: one module per paper table/figure + roofline.
+"""Benchmark runner: one module per paper table/figure.
 
   PYTHONPATH=src python -m benchmarks.run [--quick | --smoke]
 
@@ -24,10 +24,10 @@ def main(argv=None) -> int:
 
     from . import (bench_table1_hardware, bench_fig4_scaling_efforts,
                    bench_fig5_table2_task_times, bench_fig6_busy_cluster,
-                   bench_fig7_resilience, bench_claims, bench_roofline,
+                   bench_fig7_resilience, bench_claims,
                    bench_batch_policy, bench_context_plane,
                    bench_continuous_batching, bench_disagg, bench_elastic,
-                   bench_faults, bench_gateway, bench_live_decode)
+                   bench_faults, bench_gateway)
 
     t0 = time.time()
     if args.smoke:
@@ -36,10 +36,6 @@ def main(argv=None) -> int:
         # asserts plan/executed byte-accounting equality and the
         # budgeted-vs-idle staging-makespan criterion
         bench_context_plane.main(smoke=True)
-        # asserts slot-cached per-step decode time flat in prefix length
-        # AND paged shared-prefix admission cost / KV bytes flat in the
-        # shared-prefix length, at exact tokens vs full-forward
-        bench_live_decode.main(smoke=True)
         # asserts interactive p95 <= 1.2x unloaded under 10x batch
         # overload at equal batch work, token-exact suspend/resume, and
         # zero slot/page accounting leaks
@@ -58,7 +54,6 @@ def main(argv=None) -> int:
         # slot/page/byte leaks, and token-exact checkpoint/adopt resume
         # on both KV layouts
         bench_faults.main(smoke=True)
-        bench_roofline.main()
         print(f"\nsmoke benchmarks done in {time.time()-t0:.1f}s")
         return 0
     bench_table1_hardware.main()
@@ -79,8 +74,6 @@ def main(argv=None) -> int:
     bench_disagg.main()
     bench_elastic.main()
     bench_faults.main()
-    bench_live_decode.main()
-    bench_roofline.main()
     print(f"\nall benchmarks done in {time.time()-t0:.1f}s")
     return 0
 

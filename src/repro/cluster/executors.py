@@ -29,6 +29,7 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 
 from ..core import (ContextMode, NAIVE, OpKind, PARTIAL, PERVASIVE,
                     PlacementPlan, PlanOp, Tier, WarmPoolPolicy)
+from ..tracing import span
 from .events import EventLoop
 from .hardware import ClusterSpec
 from .scheduler import Assignment, PREFILL, Scheduler
@@ -946,6 +947,13 @@ class LiveExecutor(_PlanOpExecution):
     All simulated workers share this container's device; what is real is
     the context lifecycle — import, weight materialisation, jit compile
     on first use, and reuse on subsequent invocations.
+
+    Host spans (``repro.tracing``) name where the loop's time goes:
+    ``repro.executor.dispatch`` per routing round (stat ``routed``) with
+    ``repro.executor.route`` per ``Scheduler.route`` call,
+    ``repro.executor.step`` per stream step (stats ``rows`` and ``step``,
+    a running step number), ``repro.executor.complete`` around the
+    library's step and its completions, and ``repro.executor.warm_pool``.
     """
 
     def __init__(self, scheduler: Scheduler,
@@ -963,6 +971,7 @@ class LiveExecutor(_PlanOpExecution):
         # (worker_id, key) -> decoder kv_resume_bytes_total last metered
         self._kv_resume_seen: Dict[Tuple[str, str], int] = {}
         self.staging_s = 0.0                # wall seconds materialising
+        self._steps = 0                     # stream steps run (span ids)
         self._t0 = time.perf_counter()
 
     def now(self) -> float:
@@ -973,18 +982,19 @@ class LiveExecutor(_PlanOpExecution):
     def _apply_warm_pool(self) -> int:
         """Compile Replicate intents through the context plane and run the
         SAME plan ops the sim executes — here the loaders really run."""
-        if self.warm_pool is None:
-            return 0
-        plane = self.sched.plane
-        view = self.sched.view(now=self.now())
-        intents = list(plane.recovery_intents(view))
-        intents += self.warm_pool.intents(view)
-        if not intents:
-            return 0
-        plan = plane.compile(intents, view)
-        plane.commit(plan, now=view.now)
-        self.execute_plan(plan)
-        return len(plan.acquire_ops())
+        with span("repro.executor.warm_pool"):
+            if self.warm_pool is None:
+                return 0
+            plane = self.sched.plane
+            view = self.sched.view(now=self.now())
+            intents = list(plane.recovery_intents(view))
+            intents += self.warm_pool.intents(view)
+            if not intents:
+                return 0
+            plan = plane.compile(intents, view)
+            plane.commit(plan, now=view.now)
+            self.execute_plan(plan)
+            return len(plan.acquire_ops())
 
     def _make_ready(self, lib) -> None:
         """Materialise ``lib`` if it is not hosted yet (weights, compile),
@@ -1019,32 +1029,39 @@ class LiveExecutor(_PlanOpExecution):
         self._apply_warm_pool()
 
     def _dispatch_all(self) -> bool:
-        progressed = False
-        while True:
-            a = self.sched.route()
-            if a is None:
-                return progressed
-            progressed = True
-            a.t_dispatch = self.now()
-            req, w = a.request, a.worker
-            self.sched.on_start(a)
-            if req.phase == PREFILL:
-                self._run_prefill(a)
-                continue
-            if req.exclusive:
-                self._run_exclusive(a)
-                continue
-            self._stream_assign[req.request_id] = a
-            if a.preempt is not None:
-                self._suspend_victim(a)
-            if not a.join:              # founding member: open the batch
-                lib = w.library_for(
-                    self.sched.registry.recipes[req.recipe_key])
-                self._make_ready(lib)
-                self.sched.on_staged(a)
-                self._open.append((w, req.recipe_key))
-            if a.kv_ship is not None:
-                self._ship_kv(a)
+        with span("repro.executor.dispatch") as round_span:
+            routed = 0
+            while True:
+                with span("repro.executor.route"):
+                    a = self.sched.route()
+                if a is None:
+                    break
+                routed += 1
+                self._dispatch(a)
+            round_span.set_metadata(routed=routed)
+        return routed > 0
+
+    def _dispatch(self, a: Assignment) -> None:
+        a.t_dispatch = self.now()
+        req, w = a.request, a.worker
+        self.sched.on_start(a)
+        if req.phase == PREFILL:
+            self._run_prefill(a)
+            return
+        if req.exclusive:
+            self._run_exclusive(a)
+            return
+        self._stream_assign[req.request_id] = a
+        if a.preempt is not None:
+            self._suspend_victim(a)
+        if not a.join:                  # founding member: open the batch
+            lib = w.library_for(
+                self.sched.registry.recipes[req.recipe_key])
+            self._make_ready(lib)
+            self.sched.on_staged(a)
+            self._open.append((w, req.recipe_key))
+        if a.kv_ship is not None:
+            self._ship_kv(a)
 
     def _run_prefill(self, a: Assignment) -> None:
         """Run a PREFILL-phase dispatch to completion: materialise the
@@ -1137,42 +1154,48 @@ class LiveExecutor(_PlanOpExecution):
             lib.activate()
             members = list(lib.batch.values())
             step_fn = self.step_fns.get(key)
+            self._steps += 1
             if step_fn is not None:
-                outs = step_fn(lib.context.payloads, members)
-                for rid, frag in outs.items():
-                    self.results.setdefault(rid, []).append(frag)
-                # slot budgets from measured memory: a step function that
-                # hosts a slot-pool decoder exposes the REAL per-slot cache
-                # footprint after its first admission prefill; feed it back
-                # so this recipe's slot budgets stop using the
-                # KV_BYTES_PER_PARAM analytic guess (ROADMAP item).
-                dec = lib.context.payloads.get("_stream_decoder")
-                measured = int(getattr(dec, "measured_slot_bytes", 0) or 0)
-                if measured and measured != lib.recipe.measured_slot_bytes:
-                    lib.recipe.record_slot_bytes(measured)
-                # meter KV snapshots the decoder restored this step
-                # (resume happens inside the step_fn, so delta-track it)
-                total = int(getattr(dec, "kv_resume_bytes_total", 0) or 0)
-                seen = self._kv_resume_seen.get((w.worker_id, key), 0)
-                if total > seen:
-                    self.sched.plane.record_kv_resume(key, w.zone,
-                                                      total - seen)
-                    self._kv_resume_seen[(w.worker_id, key)] = total
-            finished = lib.step()
-            now = self.now()
+                with span("repro.executor.step", rows=len(members),
+                          step=self._steps):
+                    self._run_step_fn(step_fn, w, key, lib, members)
+            with span("repro.executor.complete"):
+                finished = lib.step()
+                now = self.now()
+                for r in members:
+                    if r.t_first_step is None:
+                        r.t_first_step = now
+                for r in finished:
+                    a = self._stream_assign.pop(r.request_id, None)
+                    if a is not None:
+                        self.sched.on_complete(a, a.t_dispatch, now,
+                                               t_first_step=r.t_first_step)
             stepped = True
-            for r in members:
-                if r.t_first_step is None:
-                    r.t_first_step = now
-            for r in finished:
-                a = self._stream_assign.pop(r.request_id, None)
-                if a is not None:
-                    self.sched.on_complete(a, a.t_dispatch, now,
-                                           t_first_step=r.t_first_step)
             if not lib.batch:
                 self._open.remove((w, key))
                 self.sched.close_stream(w.worker_id, key)
         return stepped
+
+    def _run_step_fn(self, step_fn, w: Worker, key: str, lib,
+                     members: list) -> None:
+        outs = step_fn(lib.context.payloads, members)
+        for rid, frag in outs.items():
+            self.results.setdefault(rid, []).append(frag)
+        # slot budgets from measured memory: a step function that hosts a
+        # slot-pool decoder exposes the REAL per-slot cache footprint after
+        # its first admission prefill; feed it back so this recipe's slot
+        # budgets stop using the KV_BYTES_PER_PARAM analytic guess.
+        dec = lib.context.payloads.get("_stream_decoder")
+        measured = int(getattr(dec, "measured_slot_bytes", 0) or 0)
+        if measured and measured != lib.recipe.measured_slot_bytes:
+            lib.recipe.record_slot_bytes(measured)
+        # meter KV snapshots the decoder restored this step (resume
+        # happens inside the step_fn, so delta-track it)
+        total = int(getattr(dec, "kv_resume_bytes_total", 0) or 0)
+        seen = self._kv_resume_seen.get((w.worker_id, key), 0)
+        if total > seen:
+            self.sched.plane.record_kv_resume(key, w.zone, total - seen)
+            self._kv_resume_seen[(w.worker_id, key)] = total
 
     def run(self) -> float:
         while not self.sched.done:

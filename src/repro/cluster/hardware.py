@@ -41,8 +41,7 @@ REF_ACTIVE_PARAMS = 1.71e9          # SmolLM2-1.7B (the calibration anchor)
 # throughput gain — the headroom continuous admission harvests.  The live
 # slot-pool decoder (inference/streaming.py) realises the same shape: one
 # cached decode_step per batch whose cost is independent of each row's
-# prefix length, so sim and live step-time curves agree
-# (benchmarks/bench_live_decode.py).
+# prefix length.
 DECODE_FIXED_FRAC = 0.75
 
 # Prefill is the OTHER phase: a long prompt is one big matmul, so its cost

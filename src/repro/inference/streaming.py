@@ -84,6 +84,7 @@ import numpy as np
 from ..data.prompts import parse_verdict
 from ..data.tokenizer import PAD
 from ..models import model as M
+from ..tracing import span
 from .pff import PROMPT_LEN
 
 
@@ -96,6 +97,21 @@ def _next_pow2(n: int) -> int:
 
 def _round_up(n: int, m: int) -> int:
     return ((n + m - 1) // m) * m
+
+
+def _named(fn, *args, **kwargs):
+    """``fn`` with ``args`` bound, keeping ``fn``'s name, so its jitted
+    program reads ``jit_<name>`` in a profile (a bare partial reads
+    ``jit__unknown``)."""
+    bound = functools.partial(fn, *args, **kwargs)
+    bound.__name__ = fn.__name__
+    return bound
+
+
+def copy_page(stages, dst, src):
+    """Every layer's KV page ``src`` copied onto page ``dst``."""
+    return jax.tree_util.tree_map(lambda x: x.at[:, dst].set(x[:, src]),
+                                  stages)
 
 
 class SlotPool:
@@ -324,16 +340,16 @@ class StreamingDecoder:
         self._tokens: Dict[int, List[int]] = {}   # rid -> prompt+generated
         self._prompt_end: Dict[int, int] = {}
         self.truncated: Dict[int, bool] = {}      # rid -> prompt was clipped
-        self._fwd = jax.jit(
-            lambda p, toks: M.forward(cfg, p, {"tokens": toks}))
-        self._decode = jax.jit(functools.partial(M.decode_step, cfg))
-        self._prefill_slots = jax.jit(functools.partial(
+
+        def forward(p, toks):
+            return M.forward(cfg, p, {"tokens": toks})
+
+        self._fwd = jax.jit(forward)
+        self._decode = jax.jit(_named(M.decode_step, cfg))
+        self._prefill_slots = jax.jit(_named(
             M.prefill_into_slots, cfg, max_len=self.max_len))
-        self._prefill_pages = jax.jit(functools.partial(
-            M.prefill_into_pages, cfg))
-        self._copy_page = jax.jit(lambda stages, dst, src: jax.tree_util.
-                                  tree_map(lambda x: x.at[:, dst].
-                                           set(x[:, src]), stages))
+        self._prefill_pages = jax.jit(_named(M.prefill_into_pages, cfg))
+        self._copy_page = jax.jit(copy_page)
         self._shapes: set = set()                 # compile-shape audit
         self.pool = SlotPool(b_max or 0)
         self._cache = None                        # device cache pytree
@@ -569,10 +585,12 @@ class StreamingDecoder:
         active = [r for r in rids if r in self.pool.slot_of]
         fresh = [r for r in rids if r not in self.pool.slot_of]
         out: Dict[int, int] = {}
-        if len(fresh) > self.pool.free:
-            self._grow(len(self.pool.slot_of) + len(fresh))
-        elif fresh and self._cache is None:       # b_max pre-sized the pool
-            self._cache = self._fresh_cache(self.pool.capacity)
+        if len(fresh) > self.pool.free or (fresh and self._cache is None):
+            with span("repro.decoder.grow"):
+                if len(fresh) > self.pool.free:
+                    self._grow(len(self.pool.slot_of) + len(fresh))
+                else:                             # b_max pre-sized the pool
+                    self._cache = self._fresh_cache(self.pool.capacity)
         if active:
             out.update(self._decode_active(active))
         if fresh:
@@ -595,7 +613,8 @@ class StreamingDecoder:
 
     def _sync_table(self) -> None:
         if self.paged and self._table_dirty:
-            self._cache["table"] = jax.numpy.asarray(self._table)
+            with span("repro.decoder.table_sync"):
+                self._cache["table"] = jax.numpy.asarray(self._table)
             self._table_dirty = False
 
     @property
@@ -639,12 +658,13 @@ class StreamingDecoder:
         self.shared_tokens_total += len(shared) * P
         return len(shared) * P
 
-    def _ensure_writable(self, rid: int) -> None:
+    def _ensure_writable(self, rid: int) -> int:
         """Guarantee the page receiving this step's decode write is
         exclusively owned.  Unmapped (ring entered a new page) → alloc;
         shared (ring WRAPPED into a refcounted prefix page) → copy-on-
         write; exclusively owned but indexed → purge the index entry
-        (the in-place write is about to change the page's bytes)."""
+        (the in-place write is about to change the page's bytes).
+        Returns the pages copied: 1 for a copy-on-write, else 0."""
         T = self.max_pages * self.page_size
         pos = len(self._tokens[rid]) - 1          # slot this token writes
         pi = (pos % T) // self.page_size
@@ -653,7 +673,8 @@ class StreamingDecoder:
         if page == PagePool.TRASH:
             self._table[slot, pi] = self.pages.alloc()
             self._table_dirty = True
-        elif self.pages.refcount(page) > 1:
+            return 0
+        if self.pages.refcount(page) > 1:
             fresh = self.pages.alloc()
             self._cache["stages"] = self._copy_page(
                 self._cache["stages"], np.int32(fresh), np.int32(page))
@@ -661,31 +682,59 @@ class StreamingDecoder:
                 self.prefix.forget_page(page)
             self._table[slot, pi] = fresh
             self._table_dirty = True
-        else:
-            self.prefix.forget_page(page)
+            return 1
+        self.prefix.forget_page(page)
+        return 0
 
     # -- device steps ---------------------------------------------------
+    def _launch(self, program: str, shape: tuple, **stats):
+        """The span around one call of a jitted step ``program``, with
+        the step's counters as stats: rows and tokens, real and padded;
+        ``new_shape`` 1 when ``shape`` is new to the compile-shape audit
+        (the call compiles or loads a program); and the page pool's pages
+        in use and reserved, the trash page left out."""
+        stats["new_shape"] = int(shape not in self._shapes)
+        self._shapes.add(shape)
+        if self.pages is not None:
+            stats.update(pages_in_use=self.pages.in_use,
+                         pages_reserved=self.pages.n_pages - 1)
+        return span("repro.decoder.launch", program=program, **stats)
+
+    def _sample(self, logits, picks: List[Tuple[int, tuple]]
+                ) -> Dict[int, int]:
+        """Greedy next tokens: ``logits`` fetched to the host (waiting
+        for the device), then for each ``(rid, index)`` of ``picks`` the
+        argmax of ``logits[index]``, appended to the rid's tokens."""
+        with span("repro.decoder.fetch"):
+            logits = np.asarray(logits)
+        out: Dict[int, int] = {}
+        with span("repro.decoder.sample"):
+            for r, index in picks:
+                nxt = int(np.argmax(logits[index]))
+                self._tokens[r].append(nxt)
+                out[r] = nxt
+        return out
+
     def _decode_active(self, active: List[int]) -> Dict[int, int]:
         B = self.pool.capacity
+        if self.paged:
+            with span("repro.decoder.pages") as sp:
+                sp.set_metadata(cow=sum(self._ensure_writable(r)
+                                        for r in active))
         toks = np.full((B, 1), PAD, dtype=np.int32)
         mask = np.zeros((B,), dtype=bool)
         for r in active:
-            if self.paged:
-                self._ensure_writable(r)
             s = self.pool.slot_of[r]
             toks[s, 0] = self._tokens[r][-1]
             mask[s] = True
         self._sync_table()
-        self._shapes.add(("decode", B))
-        logits, self._cache = self._decode(self.params, self._cache, toks,
-                                           mask)
-        logits = np.asarray(logits)
-        out: Dict[int, int] = {}
-        for r in active:
-            nxt = int(np.argmax(logits[self.pool.slot_of[r], -1]))
-            self._tokens[r].append(nxt)
-            out[r] = nxt
-        return out
+        with self._launch("decode_step", ("decode", B), rows=len(active),
+                          padded_rows=B, tokens=len(active),
+                          padded_tokens=B):
+            logits, self._cache = self._decode(self.params, self._cache,
+                                               toks, mask)
+        return self._sample(logits, [(r, (self.pool.slot_of[r], -1))
+                                     for r in active])
 
     def _admit(self, fresh: List[int]) -> Dict[int, int]:
         """Prefill for newly admitted rows.  The admission batch is
@@ -695,7 +744,8 @@ class StreamingDecoder:
         Paged: only each row's unshared TAIL is prefilled."""
         slots = [self.pool.bind(r) for r in fresh]
         if self.paged:
-            bases = [self._bind_pages(r) for r in fresh]
+            with span("repro.decoder.pages"):
+                bases = [self._bind_pages(r) for r in fresh]
             seqs = [self._tokens[r][b:] for r, b in zip(fresh, bases)]
         else:
             bases = [0] * len(fresh)
@@ -711,16 +761,21 @@ class StreamingDecoder:
         slot_arr = np.asarray(slots + [slots[0]] * pad, np.int32)
         len_arr = np.asarray(lens + [lens[0]] * pad, np.int32)
         self.prefill_tokens_total += sum(lens)
-        self._shapes.add(("prefill", Bn, S, self.pool.capacity))
-        if self.paged:
-            base_arr = np.asarray(bases + [bases[0]] * pad, np.int32)
-            self._sync_table()
-            logits, self._cache = self._prefill_pages(
-                self.params, {"tokens": arr}, self._cache, slot_arr,
-                base_arr, len_arr)
-        else:
-            logits, self._cache = self._prefill_slots(
-                self.params, {"tokens": arr}, self._cache, slot_arr, len_arr)
+        base_arr = np.asarray(bases + [bases[0]] * pad, np.int32)
+        self._sync_table()
+        with self._launch("prefill_into_pages" if self.paged
+                          else "prefill_into_slots",
+                          ("prefill", Bn, S, self.pool.capacity),
+                          rows=len(fresh), padded_rows=Bn, tokens=sum(lens),
+                          padded_tokens=Bn * S):
+            if self.paged:
+                logits, self._cache = self._prefill_pages(
+                    self.params, {"tokens": arr}, self._cache, slot_arr,
+                    base_arr, len_arr)
+            else:
+                logits, self._cache = self._prefill_slots(
+                    self.params, {"tokens": arr}, self._cache, slot_arr,
+                    len_arr)
         if not self.measured_slot_bytes:
             if self.paged:
                 self.measured_slot_bytes = self.page_bytes * self.max_pages
@@ -732,13 +787,7 @@ class StreamingDecoder:
                 total = sum(x.nbytes
                             for x in jax.tree_util.tree_leaves(self._cache))
                 self.measured_slot_bytes = int(total // self.pool.capacity)
-        logits = np.asarray(logits)
-        out: Dict[int, int] = {}
-        for i, r in enumerate(fresh):
-            nxt = int(np.argmax(logits[i, 0]))
-            self._tokens[r].append(nxt)
-            out[r] = nxt
-        return out
+        return self._sample(logits, [(r, (i, 0)) for i, r in enumerate(fresh)])
 
     def _grow(self, needed: int) -> None:
         """Capacity to the next power of two ≥ ``needed``; live state is
@@ -791,14 +840,12 @@ class StreamingDecoder:
         arr = np.full((B, S), PAD, dtype=np.int32)
         for i, s in enumerate(seqs):
             arr[i, :len(s)] = s
-        self._shapes.add(("full", B, S))
-        logits = np.asarray(self._fwd(self.params, arr))
-        out: Dict[int, int] = {}
-        for i, rid in enumerate(rids):
-            nxt = int(np.argmax(logits[i, lens[i] - 1]))
-            self._tokens[rid].append(nxt)
-            out[rid] = nxt
-        return out
+        with self._launch("forward", ("full", B, S), rows=len(rids),
+                          padded_rows=B, tokens=sum(lens),
+                          padded_tokens=B * S):
+            logits = self._fwd(self.params, arr)
+        return self._sample(logits, [(r, (i, lens[i] - 1))
+                                     for i, r in enumerate(rids)])
 
     @property
     def shape_buckets(self) -> int:
@@ -849,25 +896,29 @@ def make_pff_step_fn(prompt_len: int = PROMPT_LEN, *,
         return dec
 
     def step_fn(payloads, members):
-        dec = _decoder(payloads)
-        present = {r.request_id for r in members}
-        for rid in dec.active_rids():
-            if rid not in present:                # requeued away mid-batch
-                dec.finish(rid)
-        for r in members:
-            # a preempted member coming back: restore its KV snapshot
-            # in place of the admission prefill (suspend removed it from
-            # active_rids, so the cleanup above never touches it)
-            if dec.has_suspended(r.request_id):
-                dec.resume(r.request_id)
-        for r in members:
-            dec.ensure(r.request_id, r.payload)
-            if dec.truncated.get(r.request_id):
-                r.truncated = True
-        out = dec.step([r.request_id for r in members])
-        for r in members:
-            if r.steps_done + 1 >= r.n_units:    # last step: free state
-                dec.finish(r.request_id)
+        with span("repro.decoder.step", rows=len(members)):
+            with span("repro.decoder.membership"):
+                dec = _decoder(payloads)
+                present = {r.request_id for r in members}
+                for rid in dec.active_rids():
+                    if rid not in present:        # requeued away mid-batch
+                        dec.finish(rid)
+                for r in members:
+                    # a preempted member coming back: restore its KV
+                    # snapshot in place of the admission prefill (suspend
+                    # removed it from active_rids, so the cleanup above
+                    # never touches it)
+                    if dec.has_suspended(r.request_id):
+                        dec.resume(r.request_id)
+                for r in members:
+                    dec.ensure(r.request_id, r.payload)
+                    if dec.truncated.get(r.request_id):
+                        r.truncated = True
+            out = dec.step([r.request_id for r in members])
+            with span("repro.decoder.membership"):
+                for r in members:
+                    if r.steps_done + 1 >= r.n_units:  # last step: free
+                        dec.finish(r.request_id)
         return out
 
     def prefill(payloads, request) -> Tuple[int, List[int]]:
