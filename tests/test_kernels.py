@@ -169,6 +169,8 @@ class TestPagedDecodeAttention:
         (1, 128, 2, 4, 4, 64),
         (2, 128, 4, 8, 2, 64),
         (2, 256, 2, 4, 2, 128),
+        (2, 64, 7, 32, 32, 64),         # the smollm2 cell's geometry (MHA)
+        (2, 64, 3, 32, 8, 128),         # Granite-3.0-8B's GQA widths
     ])
     def test_sweep_vs_ref_and_dense(self, dtype, B, P, max_pages, H, K, hd):
         """Pallas-interpret == paged ref == dense ref over the gathered
@@ -213,6 +215,27 @@ class TestPagedDecodeAttention:
             np.testing.assert_allclose(
                 np.asarray(ref[i]), np.asarray(solo[0]), rtol=2e-5,
                 atol=2e-5, err_msg=f"row {i} != its own gathered ring")
+
+    @pytest.mark.parametrize("H,K,hd", [(32, 32, 64), (32, 8, 128)])
+    def test_vector_n_valid_trailing_trash(self, H, K, hd):
+        """Rows end mid-page and their trailing table entries are 0 (the
+        trash page), as the decoder leaves them: the kernel's dead steps
+        repeat the last live page and must add nothing."""
+        B, P, max_pages = 3, 64, 4
+        q, kp, vp, table = _paged(jax.random.PRNGKey(15), B,
+                                  1 + B * max_pages, P, max_pages, H, K, hd,
+                                  jnp.float32)
+        nv = np.asarray([P + 5, 3 * P - 1, 17], np.int32)
+        tbl = np.asarray(table).copy()
+        for b, n in enumerate(nv):
+            tbl[b, -(-n // P):] = 0
+        kp = kp.at[0].set(999.0)
+        out = decode_attention_paged_pallas(q, kp, vp, jnp.asarray(tbl),
+                                            jnp.asarray(nv), interpret=True)
+        ref = decode_attention_paged_ref(q, kp, vp, jnp.asarray(tbl),
+                                         jnp.asarray(nv))
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   rtol=2e-5, atol=2e-5)
 
     def test_unmapped_pages_inert(self):
         """Entries past the valid length (0 = trash sentinel) must not

@@ -91,7 +91,14 @@ def test_full_width_decode_step(one_chip, cfg):
     mask = jax.ShapeDtypeStruct((B,), jnp.bool_, sharding=one_chip)
     compiled = jax.jit(functools.partial(M.decode_step, cfg)).lower(
         params, cache, toks, mask).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    # the paged kernel reads the pool as stored, (n_pages, P, K, hd): no
+    # instruction may produce the heads-major (n_pages, K, P, hd) copy
+    transposed = (f"bf16[{1 + B * MAX_PAGES},{cfg.n_kv_heads},{PAGE},"
+                  f"{cfg.resolved_head_dim}]")
+    assert not [ln for ln in text.splitlines()
+                if f"= {transposed}" in ln], transposed
     _fits(compiled)
 
 
