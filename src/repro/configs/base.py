@@ -124,9 +124,26 @@ class ModelConfig:
     # "" = cache in model dtype.
     kv_cache_dtype: str = ""
     norm_eps: float = 1e-5
+    # scalar multipliers of the residual and attention paths (Granite);
+    # the defaults leave the block as it is, and only the dense block
+    # applies the others
+    embedding_multiplier: float = 1.0   # h0 = embed[ids] * m
+    residual_multiplier: float = 1.0    # h = h + m * branch(norm(h)), both
+    attention_multiplier: float = 0.0   # softmax scale; 0 -> 1/sqrt(head_dim)
+    logits_scaling: float = 1.0         # logits = head(norm(h)) / s
     # parallelism defaults for this arch
     parallel: ParallelConfig = field(default_factory=ParallelConfig)
     source: str = ""                  # citation
+
+    def __post_init__(self):
+        scaled = {k: v for k, v, default in (
+            ("embedding_multiplier", self.embedding_multiplier, 1.0),
+            ("residual_multiplier", self.residual_multiplier, 1.0),
+            ("attention_multiplier", self.attention_multiplier, 0.0),
+            ("logits_scaling", self.logits_scaling, 1.0)) if v != default}
+        if scaled and self.family != "dense":
+            raise ValueError(f"{self.arch_id}: {sorted(scaled)} are applied "
+                             f"by the dense block only, not {self.family!r}")
 
     # ---- derived -----------------------------------------------------
     @property

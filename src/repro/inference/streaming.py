@@ -692,7 +692,9 @@ class StreamingDecoder:
         the step's counters as stats: rows and tokens, real and padded;
         ``new_shape`` 1 when ``shape`` is new to the compile-shape audit
         (the call compiles or loads a program); and the page pool's pages
-        in use and reserved, the trash page left out."""
+        in use and reserved, the trash page left out.  A decode launch
+        also names the attention kernel's shape: ``kv_heads``,
+        ``q_per_kv`` and ``head_dim``."""
         stats["new_shape"] = int(shape not in self._shapes)
         self._shapes.add(shape)
         if self.pages is not None:
@@ -730,7 +732,9 @@ class StreamingDecoder:
         self._sync_table()
         with self._launch("decode_step", ("decode", B), rows=len(active),
                           padded_rows=B, tokens=len(active),
-                          padded_tokens=B):
+                          padded_tokens=B, kv_heads=self.cfg.n_kv_heads,
+                          q_per_kv=self.cfg.q_per_kv,
+                          head_dim=self.cfg.resolved_head_dim):
             logits, self._cache = self._decode(self.params, self._cache,
                                                toks, mask)
         return self._sample(logits, [(r, (self.pool.slot_of[r], -1))

@@ -14,6 +14,7 @@ batch in lock-step.
 """
 from __future__ import annotations
 
+import math
 from typing import Optional, Tuple
 
 import jax
@@ -56,6 +57,12 @@ def gqa_project_qkv(p, cfg, x, positions):
     return q, k, v
 
 
+def attn_scale(cfg, hd: int) -> float:
+    """The softmax scale for heads of size ``hd``: the configuration's
+    attention multiplier, or 1/sqrt(hd) where it is 0."""
+    return cfg.attention_multiplier or 1.0 / math.sqrt(hd)
+
+
 def gqa_apply(p, cfg, x, *, positions=None, causal: bool = True):
     """Full-sequence attention (train / prefill)."""
     B, S, _ = x.shape
@@ -64,7 +71,7 @@ def gqa_apply(p, cfg, x, *, positions=None, causal: bool = True):
     q, k, v = gqa_project_qkv(p, cfg, x, positions)
     out = flash_ops.flash_attention(
         q, k, v, causal=causal, window=cfg.attn_window,
-        softcap=cfg.attn_logit_softcap)
+        softcap=cfg.attn_logit_softcap, scale=attn_scale(cfg, q.shape[-1]))
     return jnp.einsum("bshk,hkd->bsd", out, p["wo"])
 
 
@@ -211,7 +218,7 @@ def gqa_decode_paged(p, cfg, x, cache_layer, table, pos, write_mask=None):
     n_valid = jnp.minimum(pos + 1, T)
     out = decode_ops.decode_attention_paged(
         q, new_cache["k"], new_cache["v"], table, n_valid,
-        softcap=cfg.attn_logit_softcap)
+        softcap=cfg.attn_logit_softcap, scale=attn_scale(cfg, q.shape[-1]))
     y = jnp.einsum("bshk,hkd->bsd", out, p["wo"])
     return y, new_cache
 
@@ -252,7 +259,7 @@ def gqa_prefill_into_pages(p, cfg, x, cache_layer, table, positions,
     qg = q.reshape(B, S, K_h, G, hd)
     scores = jnp.einsum("bskgh,btkh->bkgst", qg, kd,
                         preferred_element_type=jnp.float32)
-    scores = scores * (1.0 / (hd ** 0.5))
+    scores = scores * attn_scale(cfg, hd)
     if cfg.attn_logit_softcap > 0.0:
         scores = cfg.attn_logit_softcap * jnp.tanh(
             scores / cfg.attn_logit_softcap)
@@ -294,7 +301,8 @@ def gqa_decode(p, cfg, x, cache_layer, pos):
         ck, cv = new_cache["k"], new_cache["v"]
     n_valid = jnp.minimum(pos + 1, T)                   # (B,)
     out = decode_ops.decode_attention(q, ck, cv, n_valid,
-                                      softcap=cfg.attn_logit_softcap)
+                                      softcap=cfg.attn_logit_softcap,
+                                      scale=attn_scale(cfg, q.shape[-1]))
     y = jnp.einsum("bshk,hkd->bsd", out, p["wo"])
     return y, new_cache
 

@@ -46,6 +46,13 @@ def _res_hint(x):
     return hint(x, "batch", "seq_act", "embed_act")
 
 
+def _residual(x, y, cfg):
+    """``x + y``, the branch ``y`` scaled by the residual multiplier."""
+    if cfg.residual_multiplier != 1.0:
+        y = y * cfg.residual_multiplier
+    return x + y
+
+
 # ---------------------------------------------------------------------------
 # dense / moe (GQA attention)
 # ---------------------------------------------------------------------------
@@ -58,10 +65,10 @@ def dense_init(key, cfg, dtype):
 
 
 def dense_apply(p, cfg, x, positions, extras):
-    x = x + attn.gqa_apply(p["attn"], cfg, _norm(x, p["ln1"], cfg),
-                           positions=positions)
+    x = _residual(x, attn.gqa_apply(p["attn"], cfg, _norm(x, p["ln1"], cfg),
+                                    positions=positions), cfg)
     x = _res_hint(x)
-    x = x + mlp_apply(p["mlp"], _norm(x, p["ln2"], cfg))
+    x = _residual(x, mlp_apply(p["mlp"], _norm(x, p["ln2"], cfg)), cfg)
     return _res_hint(x), ZERO
 
 
@@ -71,10 +78,11 @@ def dense_prefill(p, cfg, x, positions, extras, max_len):
     from ..kernels.flash_attention import ops as flash_ops
     out = flash_ops.flash_attention(q, k, v, causal=True,
                                     window=cfg.attn_window,
-                                    softcap=cfg.attn_logit_softcap)
-    x = x + jnp.einsum("bshk,hkd->bsd", out, p["attn"]["wo"])
+                                    softcap=cfg.attn_logit_softcap,
+                                    scale=attn.attn_scale(cfg, q.shape[-1]))
+    x = _residual(x, jnp.einsum("bshk,hkd->bsd", out, p["attn"]["wo"]), cfg)
     x = _res_hint(x)
-    x = x + mlp_apply(p["mlp"], _norm(x, p["ln2"], cfg))
+    x = _residual(x, mlp_apply(p["mlp"], _norm(x, p["ln2"], cfg)), cfg)
     T = attn.gqa_cache_len(cfg, max_len)
     B = x.shape[0]
     empty = attn.gqa_empty_cache_layer(cfg, B, max_len, k.dtype)
@@ -96,8 +104,8 @@ def dense_decode(p, cfg, x, cache_layer, pos, extras):
     y, cache_layer = _gqa_decode_routed(p["attn"], cfg,
                                         _norm(x, p["ln1"], cfg), cache_layer,
                                         pos, extras)
-    x = x + y
-    x = x + mlp_apply(p["mlp"], _norm(x, p["ln2"], cfg))
+    x = _residual(x, y, cfg)
+    x = _residual(x, mlp_apply(p["mlp"], _norm(x, p["ln2"], cfg)), cfg)
     return x, cache_layer
 
 
@@ -107,9 +115,9 @@ def dense_prefill_paged(p, cfg, x, positions, cache_layer, table, lengths):
     y, cache_layer = attn.gqa_prefill_into_pages(p["attn"], cfg, h,
                                                  cache_layer, table,
                                                  positions, lengths)
-    x = x + y
+    x = _residual(x, y, cfg)
     x = _res_hint(x)
-    x = x + mlp_apply(p["mlp"], _norm(x, p["ln2"], cfg))
+    x = _residual(x, mlp_apply(p["mlp"], _norm(x, p["ln2"], cfg)), cfg)
     return _res_hint(x), cache_layer
 
 

@@ -112,8 +112,8 @@ def mlp_apply(p, x):
 # Embedding / unembedding
 # ---------------------------------------------------------------------------
 
-def embed_init(key, vocab: int, d_model: int, dtype):
-    return trunc_normal(key, (vocab, d_model), scale=0.02, dtype=dtype)
+def embed_init(key, vocab: int, d_model: int, dtype, scale: float = 0.02):
+    return trunc_normal(key, (vocab, d_model), scale=scale, dtype=dtype)
 
 
 def embed_apply(table, tokens):

@@ -76,7 +76,10 @@ def init_params(cfg, key) -> Dict[str, Any]:
     dtype = jnp.dtype(cfg.dtype)
     keys = jax.random.split(key, 8)
     params: Dict[str, Any] = {
-        "embed": embed_init(keys[0], cfg.vocab_size, cfg.d_model, dtype),
+        # the table is drawn so that its scaled rows, embed[ids] * m, start
+        # at the initialiser's scale (0.02) whatever the multiplier m
+        "embed": embed_init(keys[0], cfg.vocab_size, cfg.d_model, dtype,
+                            scale=0.02 / cfg.embedding_multiplier),
         "final_norm": (None if cfg.nonparametric_norm
                        else rmsnorm_init(cfg.d_model, dtype)),
         "stages": [
@@ -120,6 +123,8 @@ def init_params(cfg, key) -> Dict[str, Any]:
 
 def _embed_tokens(cfg, params, tokens, base_pos=None):
     x = jnp.take(params["embed"], tokens, axis=0)
+    if cfg.embedding_multiplier != 1.0:
+        x = x * cfg.embedding_multiplier
     if cfg.rope_theta <= 0:      # sinusoidal absolute positions (whisper)
         S = tokens.shape[1]
         table = jnp.asarray(sinusoidal_positions(
@@ -238,6 +243,8 @@ def _unembed(cfg, params, h):
         logits = jnp.einsum("...d,vd->...v", h, params["embed"])
     else:
         logits = jnp.einsum("...d,dv->...v", h, params["lm_head"])
+    if cfg.logits_scaling != 1.0:
+        logits = logits / cfg.logits_scaling
     return logits
 
 

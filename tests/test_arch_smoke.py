@@ -171,3 +171,16 @@ def test_int8_kv_cache_decode_close():
                                     toks[:, 16 + t:17 + t])
         assert float(jnp.abs(ld - ld8).max()) < 0.05
         assert bool((jnp.argmax(ld, -1) == jnp.argmax(ld8, -1)).all())
+
+
+@pytest.mark.parametrize("family", ["moe", "ssm", "hybrid", "vlm", "audio"])
+def test_multipliers_only_on_the_dense_block(family):
+    """Granite's scalar multipliers are applied by the dense block alone;
+    any other family refuses them, so no model silently runs without."""
+    base = get_config("granite-3-8b")
+    assert base.family == "dense" and base.residual_multiplier == 0.22
+    with pytest.raises(ValueError, match="dense block only"):
+        base.with_(family=family)
+    plain = base.with_(embedding_multiplier=1.0, residual_multiplier=1.0,
+                       attention_multiplier=0.0, logits_scaling=1.0)
+    assert plain.with_(family=family).family == family

@@ -193,6 +193,25 @@ class TestPagedDecodeAttention:
                                        np.asarray(dense, np.float32),
                                        **TOL[dtype])
 
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    def test_granite_attention_multiplier(self, dtype):
+        """Granite's widths (K = 8, G = 4, hd 128) with its softmax scale,
+        ``attention_multiplier`` 1/128 in place of 1/sqrt(128): the grouped
+        body applies the scale it is given, as the reference does."""
+        B, P, max_pages, H, K, hd = 2, 64, 3, 32, 8, 128
+        q, kp, vp, table = _paged(jax.random.PRNGKey(16), B,
+                                  1 + B * max_pages, P, max_pages, H, K, hd,
+                                  dtype)
+        nv = jnp.asarray([P + 9, P * max_pages], jnp.int32)
+        out = decode_attention_paged_pallas(q, kp, vp, table, nv,
+                                            scale=1 / 128, interpret=True)
+        ref = decode_attention_paged_ref(q, kp, vp, table, nv, scale=1 / 128)
+        np.testing.assert_allclose(np.asarray(out, np.float32),
+                                   np.asarray(ref, np.float32), **TOL[dtype])
+        unscaled = decode_attention_paged_ref(q, kp, vp, table, nv)
+        assert np.abs(np.asarray(ref, np.float32)
+                      - np.asarray(unscaled, np.float32)).max() > 0.1
+
     def test_vector_n_valid_shared_pages(self):
         """(B,) per-row lengths over a table whose first page is ALIASED
         across rows (shared prefix): parity, and each row must equal a

@@ -111,3 +111,29 @@ def test_full_width_prefill_into_pages(one_chip, cfg):
     compiled = jax.jit(functools.partial(M.prefill_into_pages, cfg)).lower(
         params, batch, cache, rows, rows, rows).compile()
     _fits(compiled)
+
+
+@pytest.mark.parametrize("program", ["decode_step", "prefill_into_pages"])
+def test_granite_stage_fits_one_chip(one_chip, program):
+    """Granite-3.0-8B's 10-layer stage at its published widths, at the
+    shapes of the PfF sweep drafted for it: 32 slots of 9 pages, a 32-row
+    admission of 464 tokens.  Decode holds the grouped paged kernel."""
+    cfg = get_config("granite-3-8b").with_(n_layers=10)
+    rows, pages = 32, 9
+    params = jax.eval_shape(lambda k: M.init_params(cfg, k),
+                            jax.random.PRNGKey(0))
+    cache = jax.eval_shape(functools.partial(
+        M.paged_cache_init, cfg, rows, 1 + rows * pages, PAGE, pages))
+    params, cache = _specs(params, one_chip), _specs(cache, one_chip)
+    S = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    if program == "decode_step":
+        compiled = jax.jit(functools.partial(M.decode_step, cfg)).lower(
+            params, cache, S((rows, 1), jnp.int32),
+            S((rows,), jnp.bool_)).compile()
+        assert "tpu_custom_call" in compiled.as_text()
+    else:
+        r = S((rows,), jnp.int32)
+        compiled = jax.jit(functools.partial(M.prefill_into_pages, cfg)).lower(
+            params, {"tokens": S((rows, 464), jnp.int32)}, cache, r, r,
+            r).compile()
+    _fits(compiled)

@@ -152,3 +152,27 @@ def test_unpaged_launches_name_their_program(setup, tmp_path, kw, program):
     assert runs[0]["program"] == program
     assert runs[0]["tokens"] == 10 and runs[0]["new_shape"] == 1
     assert all("pages_in_use" not in r for r in runs)
+
+
+@pytest.mark.parametrize("arch", ["smollm2-1.7b", "granite-3-8b"])
+def test_decode_launches_name_the_kernel_shape(tmp_path, arch):
+    """A decode launch says which paged-kernel body ran: its KV heads,
+    q-heads per KV head and head dim; an admission carries none of them."""
+    cfg = get_smoke_config(arch)
+    params = M.init_params(cfg, jax.random.PRNGKey(0))
+    dec = _mk(cfg, params, paged=True)
+
+    def drive():
+        dec.ensure_tokens(0, list(range(4, 14)))
+        dec.step([0])
+        dec.step([0])
+
+    runs = launches(traced(tmp_path, drive))
+    assert [r["program"] for r in runs] == ["prefill_into_pages",
+                                            "decode_step"]
+    assert runs[1] == dict(runs[1], kv_heads=cfg.n_kv_heads,
+                           q_per_kv=cfg.n_heads // cfg.n_kv_heads,
+                           head_dim=cfg.resolved_head_dim)
+    assert "kv_heads" not in runs[0]
+    assert (runs[1]["kv_heads"], runs[1]["q_per_kv"]) == \
+        {"smollm2-1.7b": (4, 1), "granite-3-8b": (2, 2)}[arch]
