@@ -54,11 +54,13 @@ every row, addressed through a per-row PAGE TABLE:
 
 Compiled-shape accounting: the decode step compiles once per pool
 capacity (capacities grow by doubling) with paging on or off — the page
-table is a cache VALUE, not a shape — and prefill once per (admission
-batch bucket, tail-length bucket).  Per-slot cache bytes are MEASURED
-after the first admission (``measured_slot_bytes``; for the paged
-layout this is the worst case ``max_pages * page_bytes`` a row can pin)
-and fed back into ``ContextRecipe.decode_slot_bytes`` by the live
+table is a cache VALUE, not a shape — and prefill once per admission
+bucket: (rows, tokens) for the contiguous rings, and for pages the count
+of page-wide chunk rows (each unshared tail is admitted one page per
+row, so the row width is always the page size).  Per-slot cache bytes
+are MEASURED after the first admission (``measured_slot_bytes``; for the
+paged layout this is the worst case ``max_pages * page_bytes`` a row can
+pin) and fed back into ``ContextRecipe.decode_slot_bytes`` by the live
 executor when sizing slot budgets.
 
 Over-length prompts are never silently truncated any more: with
@@ -741,37 +743,51 @@ class StreamingDecoder:
                                      for r in active])
 
     def _admit(self, fresh: List[int]) -> Dict[int, int]:
-        """Prefill for newly admitted rows.  The admission batch is
-        bucketed (rows → pow2, tokens → multiple of 8); padding rows
+        """Prefill for newly admitted rows, in one launch.  Padding rows
         DUPLICATE row 0 (same tokens, same slot/pages), so the duplicate
         scatter writes identical bytes and live rows stay untouched.
-        Paged: only each row's unshared TAIL is prefilled."""
+
+        Paged: only each request's unshared TAIL is prefilled, laid out
+        one page per row (``_chunk_rows``), so a short tail never pads
+        to the longest prompt of the admission.  Contiguous: one row per
+        request, rows → pow2, tokens → multiple of 8."""
         slots = [self.pool.bind(r) for r in fresh]
         if self.paged:
             with span("repro.decoder.pages"):
                 bases = [self._bind_pages(r) for r in fresh]
-            seqs = [self._tokens[r][b:] for r, b in zip(fresh, bases)]
+            seqs, rows, lasts = self._chunk_rows(fresh, slots, bases)
+            n_real = len(seqs)
+            S = self.page_size
+            Bn = _next_pow2(n_real)
+            if Bn > self.pool.capacity:
+                Bn = _round_up(n_real, self.pool.capacity)
         else:
-            bases = [0] * len(fresh)
             seqs = [self._tokens[r] for r in fresh]
-        S = min(_round_up(max(len(s) for s in seqs), 8), self.max_len)
+            rows = [(s, 0) for s in slots]
+            lasts = list(range(len(fresh)))
+            n_real = len(fresh)
+            S = min(_round_up(max(len(s) for s in seqs), 8), self.max_len)
+            Bn = _next_pow2(n_real)
         lens = [min(len(s), S) for s in seqs]     # exactness holds ≤ max_len
-        Bn = _next_pow2(len(fresh))
         arr = np.full((Bn, S), PAD, dtype=np.int32)
         for i, s in enumerate(seqs):
             arr[i, :lens[i]] = s[:lens[i]]
-        arr[len(fresh):] = arr[0]
-        pad = Bn - len(fresh)
-        slot_arr = np.asarray(slots + [slots[0]] * pad, np.int32)
+        arr[n_real:] = arr[0]
+        pad = Bn - n_real
+        slot_arr = np.asarray([s for s, _b in rows] + [rows[0][0]] * pad,
+                              np.int32)
+        base_arr = np.asarray([b for _s, b in rows] + [rows[0][1]] * pad,
+                              np.int32)
         len_arr = np.asarray(lens + [lens[0]] * pad, np.int32)
         self.prefill_tokens_total += sum(lens)
-        base_arr = np.asarray(bases + [bases[0]] * pad, np.int32)
         self._sync_table()
+        stats = dict(rows=len(fresh), padded_rows=Bn, tokens=sum(lens),
+                     padded_tokens=Bn * S)
+        if self.paged:
+            stats["chunks"] = n_real
         with self._launch("prefill_into_pages" if self.paged
                           else "prefill_into_slots",
-                          ("prefill", Bn, S, self.pool.capacity),
-                          rows=len(fresh), padded_rows=Bn, tokens=sum(lens),
-                          padded_tokens=Bn * S):
+                          ("prefill", Bn, S, self.pool.capacity), **stats):
             if self.paged:
                 logits, self._cache = self._prefill_pages(
                     self.params, {"tokens": arr}, self._cache, slot_arr,
@@ -791,7 +807,25 @@ class StreamingDecoder:
                 total = sum(x.nbytes
                             for x in jax.tree_util.tree_leaves(self._cache))
                 self.measured_slot_bytes = int(total // self.pool.capacity)
-        return self._sample(logits, [(r, (i, 0)) for i, r in enumerate(fresh)])
+        return self._sample(logits, [(r, (i, 0))
+                                     for r, i in zip(fresh, lasts)])
+
+    def _chunk_rows(self, fresh: List[int], slots: List[int],
+                    bases: List[int]):
+        """Each request's unshared tail cut into page-wide chunks: one row
+        ``(slot, base + j*P)`` per page of ``tokens[base:]`` (``base`` is
+        page-aligned, so each row writes exactly one page).  Returns the
+        rows' tokens, their (slot, base), and the row of each request's
+        last chunk, where its first token is sampled."""
+        P = self.page_size
+        seqs, rows, lasts = [], [], []
+        for r, slot, base in zip(fresh, slots, bases):
+            toks = self._tokens[r]
+            for b in range(base, len(toks), P):
+                seqs.append(toks[b:b + P])
+                rows.append((slot, b))
+            lasts.append(len(seqs) - 1)
+        return seqs, rows, lasts
 
     def _grow(self, needed: int) -> None:
         """Capacity to the next power of two ≥ ``needed``; live state is

@@ -468,9 +468,16 @@ def prefill_into_pages(cfg, params, batch, cache, slots, base, lengths):
     ``slots`` (Bn,) are the rows' table indices; ``lengths`` (Bn,) the true
     tail token counts (padding positions write to the trash page).  The
     caller must have mapped private pages covering ``[base, base+length)``
-    in ``cache["table"]`` before calling.  Returns (next-token logits
-    (Bn,1,V) gathered at each row's last real tail position, updated
-    cache)."""
+    in ``cache["table"]`` before calling.
+
+    Several rows may share one slot, each holding a consecutive chunk of
+    one prompt: every layer writes all rows' K/V before any row attends,
+    so a chunk reads the chunks before it from the same launch.  The
+    slot's position becomes the furthest ``base + length`` among its
+    rows, written alike by each of them (a scatter with duplicate
+    indices keeps an unspecified one of its values).  Returns
+    (next-token logits (Bn,1,V) gathered at each row's last real tail
+    position, updated cache)."""
     tokens = batch["tokens"]
     Bn, S = tokens.shape
     base = jnp.asarray(base, jnp.int32)
@@ -500,7 +507,8 @@ def prefill_into_pages(cfg, params, batch, cache, slots, base, lengths):
         x, jnp.broadcast_to(last[:, None, None], (Bn, 1, D)), axis=1)
     h = rmsnorm(h, params["final_norm"], cfg.norm_eps)
     logits = _unembed(cfg, params, h)
-    new_pos = cache["pos"].at[slots].set(base + lengths)
+    end = jnp.zeros_like(cache["pos"]).at[slots].max(base + lengths)
+    new_pos = cache["pos"].at[slots].set(end[slots])
     return logits, {**cache, "stages": new_stages, "pos": new_pos}
 
 

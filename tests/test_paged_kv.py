@@ -226,6 +226,50 @@ class TestPagedDecoder:
         decode_shapes = [s for s in dec._shapes if s[0] == "decode"]
         assert decode_shapes == [("decode", 4)]
 
+    # each admission: (shared, private) token counts per request, all in
+    # one step; the prefill grid (rows, pool capacity) it must pad to
+    CHUNKED = {
+        # 4 + 1 + 2 chunk rows, 4 slots: padded to 8; the sharers map the
+        # writer's 3 pages in the launch that writes them
+        "writer_and_two_sharers": ([(24, 5), (24, 3), (24, 10)], (8, 4)),
+        # the writer's one whole page shared, then a 20-token tail over
+        # 3 pages: 2 + 3 rows, 2 slots, padded to 6
+        "tail_spans_3_pages": ([(8, 2), (8, 20)], (6, 2)),
+        # 3 single-page prompts, 4 slots: padded to the power of two 4
+        "pads_rows": ([(0, 5), (0, 6), (0, 7)], (4, 4)),
+    }
+
+    @pytest.mark.parametrize("arch", ["smollm2-1.7b", "granite-3-8b"])
+    @pytest.mark.parametrize("case", sorted(CHUNKED))
+    def test_chunked_admission_token_exact(self, arch, case):
+        """An admission laid out one page per row is token-exact against
+        the full-forward reference, at admission and through 8 decode
+        steps, for MHA (smollm2) and GQA with its multipliers (Granite);
+        every slot's position is its prompt length, however many rows
+        repeated the slot."""
+        cfg = get_smoke_config(arch)
+        params = M.init_params(cfg, jax.random.PRNGKey(1))
+        rng = np.random.default_rng(11)
+        spec, (rows, cap) = self.CHUNKED[case]
+        shared = list(rng.integers(4, cfg.vocab_size, 24))
+        prompts = {r: shared[:n] + list(rng.integers(4, cfg.vocab_size, m))
+                   for r, (n, m) in enumerate(spec)}
+        rids = list(prompts)
+        script = [(rids, [])] * 9
+        dec = _mk(cfg, params, paged=True)
+        got = _run(dec, prompts, script[:1])
+        assert ("prefill", rows, 8, cap) in dec._shapes
+        assert dec.pool.capacity == cap
+        pos = np.asarray(dec._cache["pos"])
+        assert [int(pos[dec.pool.slot_of[r]]) for r in rids] == \
+            [len(prompts[r]) for r in rids]
+        assert dec.shared_tokens_total == sum(
+            n // 8 * 8 for n, _m in spec[1:])
+        for r, toks in _run(dec, prompts, script[1:]).items():
+            got[r] += toks
+        ref = _run(_mk(cfg, params, slot_cached=False), prompts, script)
+        assert got == ref
+
     def test_measured_slot_bytes_is_page_budget(self, setup):
         cfg, params = setup
         dec = _mk(cfg, params, paged=True)

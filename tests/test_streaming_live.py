@@ -93,9 +93,12 @@ class TestStreamingDecoder:
             dec.ensure(i, claims[i])
         for step in range(MAX_NEW):
             dec.step(list(range(6 if step < 4 else 3)))
-        # 6→pad 8 and 3→pad 4 batches, sequence growth inside one
-        # 8-multiple: at most a handful of compiled shapes
-        assert dec.shape_buckets <= 4
+        # the pool grows to 8 slots, where 6 and then 3 rows decode; the
+        # 6 prompts (63-96 tokens) admit as 11 page-wide chunk rows, past
+        # the capacity, so padded to a multiple of it
+        assert sorted(dec._shapes) == [("decode", 8),
+                                       ("prefill", 16, dec.page_size, 8)]
+        assert dec.shape_buckets == 2
 
 
 class TestSlotPoolDecoding:
@@ -162,7 +165,11 @@ class TestSlotPoolDecoding:
         for _ in range(24):
             dec.step(rids)
         assert dec.shape_buckets == buckets_after_two
-        assert dec.shape_buckets <= 3
+        # 3 prompts of 65-96 tokens: 6 chunk rows, padded to a multiple
+        # of the 4-slot pool
+        assert sorted(dec._shapes) == [("decode", 4),
+                                       ("prefill", 8, dec.page_size, 4)]
+        assert dec.shape_buckets == 2
 
     def test_b_max_presized_pool(self, setup):
         """A pool pre-sized to the library's slot budget never grows."""

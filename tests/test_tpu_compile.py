@@ -137,3 +137,34 @@ def test_granite_stage_fits_one_chip(one_chip, program):
             params, {"tokens": S((rows, 464), jnp.int32)}, cache, r, r,
             r).compile()
     _fits(compiled)
+
+
+# the PfF cells' decoders: 32 slots; smollm2 rings of 7 pages (448
+# tokens), Granite's 10-layer stage rings of 9 (576)
+CELLS = {"smollm2-1.7b": (None, 7), "granite-3-8b": (10, 9)}
+
+
+@pytest.mark.parametrize("arch, rows", [
+    ("smollm2-1.7b", 64), ("smollm2-1.7b", 192),
+    ("granite-3-8b", 64), ("granite-3-8b", 256)])
+def test_chunk_grid_prefill_fits_one_chip(one_chip, arch, rows):
+    """The paged admission's page-wide chunk grid at its published widths:
+    64 rows, a 32-request cohort of the sweep, and the largest grid the
+    cell's warm-up admits, 32 whole prompts of 6 (smollm2) or 8 (Granite)
+    pages."""
+    n_layers, pages = CELLS[arch]
+    cfg = get_config(arch)
+    if n_layers:
+        cfg = cfg.with_(n_layers=n_layers)
+    slots = 32
+    params = jax.eval_shape(lambda k: M.init_params(cfg, k),
+                            jax.random.PRNGKey(0))
+    cache = jax.eval_shape(functools.partial(
+        M.paged_cache_init, cfg, slots, 1 + slots * pages, PAGE, pages))
+    params, cache = _specs(params, one_chip), _specs(cache, one_chip)
+    S = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    r = S((rows,), jnp.int32)
+    compiled = jax.jit(functools.partial(M.prefill_into_pages, cfg)).lower(
+        params, {"tokens": S((rows, PAGE), jnp.int32)}, cache, r, r,
+        r).compile()
+    _fits(compiled)

@@ -113,14 +113,15 @@ def test_paged_launches_count_rows_tokens_pages_and_new_shapes(
     assert [r["program"] for r in runs] == (
         ["prefill_into_pages", "decode_step", "prefill_into_pages"]
         + ["decode_step"] * 10)
-    # row 0 admits alone (capacity 1); row 1 grows the pool to 2, row 0
-    # decodes, and row 1 maps the 16 shared tokens and prefills its
-    # 3-token tail (bucket 8)
-    assert runs[0] == dict(runs[0], rows=1, padded_rows=1, tokens=21,
-                           padded_tokens=24, new_shape=1, pages_in_use=3,
-                           pages_reserved=3)
-    assert runs[2] == dict(runs[2], rows=1, padded_rows=1, tokens=3,
-                           padded_tokens=8, new_shape=1, pages_reserved=6)
+    # row 0 admits alone (capacity 1) in 3 page-wide chunk rows; row 1
+    # grows the pool to 2, row 0 decodes, and row 1 maps the 16 shared
+    # tokens and prefills its 3-token tail in one chunk row
+    assert runs[0] == dict(runs[0], rows=1, chunks=3, padded_rows=3,
+                           tokens=21, padded_tokens=24, new_shape=1,
+                           pages_in_use=3, pages_reserved=3)
+    assert runs[2] == dict(runs[2], rows=1, chunks=1, padded_rows=1,
+                           tokens=3, padded_tokens=8, new_shape=1,
+                           pages_reserved=6)
     assert [r["tokens"] for r in runs if r["program"] != "decode_step"] \
         == [marks[0], marks[1] - marks[0]]
     decodes = [r for r in runs if r["program"] == "decode_step"]
@@ -128,6 +129,7 @@ def test_paged_launches_count_rows_tokens_pages_and_new_shapes(
     assert [r["new_shape"] for r in decodes] == [1] + [0] * 10
     assert [r["rows"] for r in decodes] == [1] + [2] * 10
     assert all(r["padded_rows"] == r["padded_tokens"] for r in decodes)
+    assert all("chunks" not in r for r in decodes)
     assert all(0 < r["pages_in_use"] <= r["pages_reserved"] for r in runs)
     cow = sum(st.get("cow", 0) for n, _s, _e, st in spans
               if n == "repro.decoder.pages")
@@ -151,7 +153,7 @@ def test_unpaged_launches_name_their_program(setup, tmp_path, kw, program):
     runs = launches(traced(tmp_path, drive))
     assert runs[0]["program"] == program
     assert runs[0]["tokens"] == 10 and runs[0]["new_shape"] == 1
-    assert all("pages_in_use" not in r for r in runs)
+    assert all("pages_in_use" not in r and "chunks" not in r for r in runs)
 
 
 @pytest.mark.parametrize("arch", ["smollm2-1.7b", "granite-3-8b"])
