@@ -29,11 +29,11 @@ device, fast-HBM decode device): after prefill the KV snapshot is
 exported bit-exact (`export_suspended`), parked in the destination
 worker's inbox, adopted into its slot pool, and decode resumes WITHOUT
 re-prefill — the full token stream must be BIT-EXACT vs a colocated run
-of the same claims, on both KV layouts (contiguous and paged).
+of the same claims.
 
 ``--smoke`` (the CI guard): FAILS if disaggregated throughput falls
 below colocated at equal completed work, if no KV handoff actually
-happened, if shipped tokens diverge from colocated on either layout, or
+happened, if shipped tokens diverge from colocated, or
 if any plan/moved/inflight KV byte accounting leaks.
 """
 from __future__ import annotations
@@ -165,7 +165,7 @@ LIVE_PROMPT_UNITS = 3
 LIVE_DECODE_STEPS = 8
 
 
-def _run_live(claims, recipe, *, disaggregate: bool, paged: bool):
+def _run_live(claims, recipe, *, disaggregate: bool):
     from repro.inference import make_pff_step_fn
 
     sched = Scheduler(disaggregate=disaggregate)
@@ -176,7 +176,7 @@ def _run_live(claims, recipe, *, disaggregate: bool, paged: bool):
     for c in claims:
         app.submit(key, prompt_units=LIVE_PROMPT_UNITS,
                    decode_steps=LIVE_DECODE_STEPS, payload=c)
-    ex = LiveExecutor(sched, step_fns={key: make_pff_step_fn(paged=paged)})
+    ex = LiveExecutor(sched, step_fns={key: make_pff_step_fn()})
     ex.run()
     # submission order, not request_id: ids are process-global
     toks = [ex.results[r.request_id] for r in app.requests]
@@ -192,23 +192,18 @@ def live_section(smoke: bool):
     cfg = get_smoke_config("smollm2-1.7b")
     claims = generate_claims(LIVE_CLAIMS, seed=2)
     recipe = build_context_recipe(cfg, "with_evidence")
-    for paged in (False, True):
-        layout = "paged" if paged else "contiguous"
-        base, _ = _run_live(claims, recipe, disaggregate=False, paged=paged)
-        dis, sched = _run_live(claims, recipe, disaggregate=True,
-                               paged=paged)
-        kv = sched.plane.kv_summary()
-        assert base == dis, \
-            f"{layout}: shipped-KV decode diverged from colocated"
-        assert sched.kv_ships > 0, \
-            f"{layout}: the rig never shipped — scoring regression"
-        assert sched.prefills_done == LIVE_CLAIMS
-        _assert_no_kv_leaks(sched)
-        print(f"{layout}: {LIVE_CLAIMS} requests bit-exact vs colocated "
-              f"({sched.kv_ships} ship(s), {kv['shipped_bytes']} KV bytes "
-              f"handed off, {sched.local_decodes} local)")
+    base, _ = _run_live(claims, recipe, disaggregate=False)
+    dis, sched = _run_live(claims, recipe, disaggregate=True)
+    kv = sched.plane.kv_summary()
+    assert base == dis, "shipped-KV decode diverged from colocated"
+    assert sched.kv_ships > 0, "the rig never shipped — scoring regression"
+    assert sched.prefills_done == LIVE_CLAIMS
+    _assert_no_kv_leaks(sched)
+    print(f"{LIVE_CLAIMS} requests bit-exact vs colocated "
+          f"({sched.kv_ships} ship(s), {kv['shipped_bytes']} KV bytes "
+          f"handed off, {sched.local_decodes} local)")
     if smoke:
-        print("smoke OK: shipped-KV decode token-exact on both KV layouts")
+        print("smoke OK: shipped-KV decode token-exact")
 
 
 def main(smoke: bool = False) -> int:

@@ -25,8 +25,7 @@ Claims asserted in ``--smoke`` (and full) mode:
   checkpoints are refunded, so parity covers the checkpoint plane too);
 * LIVE (this container's device): a decode stream checkpointed
   mid-flight and adopted by a fresh decoder continues TOKEN-EXACTLY vs
-  an uninterrupted reference — on both the contiguous and the paged KV
-  layout.
+  an uninterrupted reference.
 
 Usage: python -m benchmarks.run [--smoke] | python -m benchmarks.bench_faults [--smoke] [--seed N]
 """
@@ -119,7 +118,7 @@ def run_sim(ckpt_every: Optional[int], *, n_requests: int, decode_steps: int,
     }
 
 
-def run_live(paged: bool, *, n_steps: int = 24, crash_at: int = 10) -> None:
+def run_live(*, n_steps: int = 24, crash_at: int = 10) -> None:
     """LIVE bit-exactness: checkpoint a decode mid-flight, adopt the
     snapshot into a FRESH decoder (the checkpoint host), and verify the
     resumed stream's tokens equal an uninterrupted reference's."""
@@ -136,9 +135,8 @@ def run_live(paged: bool, *, n_steps: int = 24, crash_at: int = 10) -> None:
 
     def mk():
         return StreamingDecoder(cfg, params, None, None, prompt_len=32,
-                                max_len=64, paged=paged, page_size=8)
+                                max_len=64, page_size=8)
 
-    layout = "paged" if paged else "contiguous"
     ref = mk()
     ref.ensure_tokens(0, prompt)
     want = [ref.step([0])[0] for _ in range(n_steps)]
@@ -147,9 +145,9 @@ def run_live(paged: bool, *, n_steps: int = 24, crash_at: int = 10) -> None:
     src.ensure_tokens(0, prompt)
     got = [src.step([0])[0] for _ in range(crash_at)]
     snap = src.checkpoint(0)         # non-destructive: src keeps decoding
-    assert snap is not None, f"[{layout}] no snapshot for a bound slot"
+    assert snap is not None, "no snapshot for a bound slot"
     assert src.pool.slot_of.get(0) is not None, \
-        f"[{layout}] checkpoint released the source slot"
+        "checkpoint released the source slot"
     got += [src.step([0])[0] for _ in range(2)]   # steps LOST to the crash
 
     dst = mk()                       # the checkpoint host takes over
@@ -157,13 +155,10 @@ def run_live(paged: bool, *, n_steps: int = 24, crash_at: int = 10) -> None:
     dst.resume(0)
     resumed = [dst.step([0])[0] for _ in range(n_steps - crash_at)]
     assert got[:crash_at] + resumed == want, \
-        f"[{layout}] resumed stream diverged from the reference"
-    assert dst.finish(0) == want, \
-        f"[{layout}] finished token buffer diverged"
-    if paged:
-        assert dst.pages.in_use == 0 and src.pages is not None, \
-            f"[{layout}] page leak after finish"
-    print(f"  [live {layout}] {crash_at} steps + crash + resume on fresh "
+        "resumed stream diverged from the reference"
+    assert dst.finish(0) == want, "finished token buffer diverged"
+    assert dst.pages.in_use == 0, "page leak after finish"
+    print(f"  [live] {crash_at} steps + crash + resume on fresh "
           f"decoder == {n_steps}-step reference (token-exact)")
 
 
@@ -203,8 +198,7 @@ def main(smoke: bool = False, seed: int = 3) -> None:
           f"{ckpt['makespan']:.1f}s < {base['makespan']:.1f}s, waste "
           f"{ckpt['wasted']} < {base['wasted']}, detection <= lease")
 
-    run_live(paged=False)
-    run_live(paged=True)
+    run_live()
 
 
 if __name__ == "__main__":

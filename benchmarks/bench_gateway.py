@@ -18,10 +18,10 @@ Reported: per-class p95 e2e over the steady-state window, completed
 batch decode units, preemption/spill/resume counters.
 
 The LIVE section drives a real :class:`StreamingDecoder` through the
-suspend/resume path (both paged and contiguous KV layouts): a victim is
-suspended mid-decode, others keep stepping, the victim resumes — and its
-token stream must be BIT-EXACT against an uninterrupted run.  Slot and
-page accounting must balance to zero afterwards.
+suspend/resume path: a victim is suspended mid-decode, others keep
+stepping, the victim resumes — and its token stream must be BIT-EXACT
+against an uninterrupted run.  Slot and page accounting must balance to
+zero afterwards.
 
 ``--smoke`` (the CI guard): FAILS if gateway interactive p95 exceeds
 1.2x the unloaded p95, if the gateway run completes less batch work than
@@ -184,19 +184,17 @@ def sim_section(smoke: bool):
 
 
 # -- live suspend/resume ----------------------------------------------------
-def _token_exactness(cfg, params, *, paged: bool):
+def _token_exactness(cfg, params):
     """Suspend a victim mid-decode, step the others, resume: the victim's
     tokens must be bit-exact vs an uninterrupted run.  Returns the
-    (suspended, resumed) byte counters for the caller to check."""
+    suspended byte count for the caller to report."""
     import numpy as np
     from repro.inference import StreamingDecoder
 
     rng = np.random.default_rng(7)
     prompts = {r: list(rng.integers(4, cfg.vocab_size, 12 + 3 * r))
                for r in range(3)}
-    kw = dict(max_len=64, paged=paged)
-    if paged:
-        kw["page_size"] = 8
+    kw = dict(max_len=64, page_size=8)
 
     def fresh():
         dec = StreamingDecoder(cfg, params, None, None, **kw)
@@ -226,18 +224,15 @@ def _token_exactness(cfg, params, *, paged: bool):
     for r in range(3):
         ref.finish(r)
 
-    layout = "paged" if paged else "contiguous"
     assert outs[victim] == routs[victim], \
-        f"{layout}: resumed token stream diverged from the " \
-        f"uninterrupted reference ({outs[victim]} vs {routs[victim]})"
+        f"resumed token stream diverged from the uninterrupted " \
+        f"reference ({outs[victim]} vs {routs[victim]})"
     assert not dec._suspended, "suspended snapshot leaked"
     assert dec.pool.free == dec.pool.capacity, \
-        f"{layout}: slot leak ({dec.pool.free}/{dec.pool.capacity} free)"
-    if paged:
-        assert dec.pages.in_use == 0, \
-            f"{layout}: {dec.pages.in_use} page(s) leaked"
+        f"slot leak ({dec.pool.free}/{dec.pool.capacity} free)"
+    assert dec.pages.in_use == 0, f"{dec.pages.in_use} page(s) leaked"
     assert dec.kv_suspend_bytes_total == dec.kv_resume_bytes_total > 0
-    return layout, dec.kv_suspend_bytes_total
+    return dec.kv_suspend_bytes_total
 
 
 def _retention_check():
@@ -271,13 +266,11 @@ def live_section(smoke: bool):
     _retention_check()
     cfg = get_smoke_config("smollm2-1.7b")
     params = M.init_params(cfg, jax.random.PRNGKey(0))
-    for paged in (False, True):
-        layout, nbytes = _token_exactness(cfg, params, paged=paged)
-        print(f"{layout}: victim resumed bit-exact after mid-decode "
-              f"suspension ({nbytes} KV bytes spilled+restored, zero "
-              "slot/page leaks)")
+    nbytes = _token_exactness(cfg, params)
+    print(f"victim resumed bit-exact after mid-decode suspension "
+          f"({nbytes} KV bytes spilled+restored, zero slot/page leaks)")
     if smoke:
-        print("smoke OK: suspend/resume token-exact on both KV layouts")
+        print("smoke OK: suspend/resume token-exact")
 
 
 def main(smoke: bool = False) -> int:

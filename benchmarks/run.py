@@ -41,7 +41,7 @@ def main(argv=None) -> int:
         # zero slot/page accounting leaks
         bench_gateway.main(smoke=True)
         # asserts disaggregated routing >= colocated throughput at equal
-        # completed work, shipped-KV decode token-exact on both layouts,
+        # completed work, shipped-KV decode token-exact,
         # and zero KV byte leaks (planned == moved incl KV_SHIP)
         bench_disagg.main(smoke=True)
         # asserts forecast-driven elastic supply strictly beats the
@@ -52,7 +52,6 @@ def main(argv=None) -> int:
         # goodput AND wasted decode tokens under a seeded crash storm at
         # equal completed work, crash detection within one lease, zero
         # slot/page/byte leaks, and token-exact checkpoint/adopt resume
-        # on both KV layouts
         bench_faults.main(smoke=True)
         print(f"\nsmoke benchmarks done in {time.time()-t0:.1f}s")
         return 0

@@ -74,8 +74,9 @@ def _normal(key, shape, dtype=jnp.bfloat16):
 def kernel_cases(cfg, batch: int, prompt_len: int, max_len: int,
                  page_size: int):
     """(name, op, reference, args) for each main-path kernel at the served
-    shapes: the contiguous slot ring, the paged pool of a ``batch``-row
-    decoder whose rows share their first (prefix) page, and the prompt."""
+    shapes: the engine's dense ring for ``batch`` rows, the paged pool of
+    a ``batch``-row decoder whose rows share their first (prefix) page,
+    and the prompt."""
     from repro.kernels.decode_attention import ops as decode_ops
     from repro.kernels.decode_attention.ref import (
         decode_attention_paged_ref, decode_attention_ref)

@@ -1182,9 +1182,9 @@ class LiveExecutor(_PlanOpExecution):
         for rid, frag in outs.items():
             self.results.setdefault(rid, []).append(frag)
         # slot budgets from measured memory: a step function that hosts a
-        # slot-pool decoder exposes the REAL per-slot cache footprint after
-        # its first admission prefill; feed it back so this recipe's slot
-        # budgets stop using the KV_BYTES_PER_PARAM analytic guess.
+        # slot-pool decoder exposes the REAL per-slot cache footprint once
+        # its cache exists; feed it back so this recipe's slot budgets
+        # stop using the KV_BYTES_PER_PARAM analytic guess.
         dec = lib.context.payloads.get("_stream_decoder")
         measured = int(getattr(dec, "measured_slot_bytes", 0) or 0)
         if measured and measured != lib.recipe.measured_slot_bytes:

@@ -169,11 +169,10 @@ class ContextRecipe:
     def record_slot_bytes(self, nbytes: int) -> None:
         """Feed back a live-measured per-slot decode footprint (bytes).
 
-        Latest measurement wins: the figure reflects the measuring pool's
-        ring length (its ``max_len``) and layout (contiguous per-slot
-        rings, or the paged worst-case ``max_pages * page_bytes``), so a
-        decoder re-built with a longer ring simply re-records after its
-        first admission."""
+        Latest measurement wins: the figure is the measuring pool's
+        worst-case page allotment per row (``max_pages * page_bytes``,
+        set by its ``max_len``), so a decoder re-built with a longer ring
+        simply re-records once its cache exists."""
         if nbytes > 0:
             _MEASURED_SLOT_BYTES[self.key] = int(nbytes)
 

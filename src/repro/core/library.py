@@ -87,8 +87,8 @@ class Library:
         footprint is the MEASURED per-slot cache bytes once the executor
         has fed one back (see ``ContextRecipe.record_slot_bytes``); the
         analytic ``KV_BYTES_PER_PARAM`` estimate only seeds the first
-        admission.  With the paged KV layout the measured figure is the
-        worst-case per-request page allotment (``max_pages * page_bytes``)
+        admission.  The measured figure is the decoder's worst-case
+        per-request page allotment (``max_pages * page_bytes``)
         — shared-prefix pages are refcounted device-side, so the budget
         is conservative and admission arithmetic stays unchanged."""
         free = device_bytes - self.recipe.nbytes(Tier.DEVICE)

@@ -1,30 +1,28 @@
-"""Live continuous-batching decoder: a persistent slot pool of KV state.
+"""Live continuous-batching decoder: a persistent slot pool over KV pages.
 
 The LIVE leg of the request-stream redesign.  A library's dynamic batch
 changes membership between decode steps, so the device batch cannot be a
 fixed (B, S) array compiled once per task.  :class:`StreamingDecoder`
 keeps the decode state RESIDENT on the device instead: a
-:class:`SlotPool` of ``capacity`` rows of KV cache that requests bind to
-on admission and free on completion.
+:class:`SlotPool` of ``capacity`` rows that requests bind to on
+admission and free on completion, over one shared pool of KV pages.
 
-* **admit** — a new request's prompt runs through a prompt-only prefill
-  that scatters its K/V + position into the shared cache at its slot,
-  without touching live rows;
+* **admit** — a new request's unshared prompt tail runs through
+  ``M.prefill_into_pages``, which writes its K/V into the row's pages
+  and sets its position without touching live rows;
 * **step** — ONE cached ``M.decode_step`` over all slots advances every
   active row by one token at O(1) FLOPs/token (each row embeds/RoPEs at
-  its own position, ring-writes at its own slot, masks at its own
-  length via the vector-``n_valid`` decode-attention kernel);
-* **finish** — the slot returns to the free list; its stale K/V is
-  either fully overwritten by the next tenant's admission prefill
-  (contiguous) or unmapped from the page table (paged), so reuse never
-  leaks context across requests.
+  its own position, writes at its own ring slot, masks at its own
+  length via the paged decode-attention kernel);
+* **finish** — the slot returns to the free list and its pages are
+  unmapped from the page table, so reuse never leaks context across
+  requests.
 
-Paged KV layout (``paged=True``, the default where
-``M.supports_paging``)
-----------------------------------------------------------------------
-The contiguous per-slot ring (B, max_len, K, hd) is replaced by
-PHYSICAL PAGE POOLS of shape (L, n_pages, page_size, K, hd) shared by
-every row, addressed through a per-row PAGE TABLE:
+Paged KV layout
+---------------
+Every layer's K/V lives in PHYSICAL PAGE POOLS of shape
+(L, n_pages, page_size, K, hd) shared by every row, addressed through a
+per-row PAGE TABLE:
 
 * ``cache["table"]`` is (B, max_pages) int32.  Row ``b``'s logical ring
   slot ``s`` (s = pos % T, T = max_pages * page_size) lives at physical
@@ -52,16 +50,18 @@ every row, addressed through a per-row PAGE TABLE:
   the original) when its refcount is > 1 — a tenant wrapping its ring
   into a shared prefix page never corrupts the other holders.
 
+The pages hold GQA K/V only (``M.supports_paging``): the slot pool
+refuses recurrent, MLA, sliding-window, int8, vision and
+cross-attention caches rather than serve them in another layout.
+
 Compiled-shape accounting: the decode step compiles once per pool
-capacity (capacities grow by doubling) with paging on or off — the page
-table is a cache VALUE, not a shape — and prefill once per admission
-bucket: (rows, tokens) for the contiguous rings, and for pages the count
-of page-wide chunk rows (each unshared tail is admitted one page per
-row, so the row width is always the page size).  Per-slot cache bytes
-are MEASURED after the first admission (``measured_slot_bytes``; for the
-paged layout this is the worst case ``max_pages * page_bytes`` a row can
-pin) and fed back into ``ContextRecipe.decode_slot_bytes`` by the live
-executor when sizing slot budgets.
+capacity (capacities grow by doubling) — the page table is a cache
+VALUE, not a shape — and prefill once per count of page-wide chunk rows
+(each unshared tail is admitted one page per row, so the row width is
+always the page size).  The per-slot cache bytes
+(``measured_slot_bytes``) are the worst case ``max_pages * page_bytes``
+a row can pin, fed back into ``ContextRecipe.decode_slot_bytes`` by the
+live executor when sizing slot budgets.
 
 Over-length prompts are never silently truncated any more: with
 ``strict_prompts=True`` admission raises; otherwise the prompt is
@@ -70,8 +70,8 @@ clipped and the request's ``truncated`` flag (surfaced through
 
 The pre-slot full-forward path (prompt + generated prefix re-run
 through ``M.forward`` every step; right-padding inert under causal
-attention) survives as ``slot_cached=False`` — the token-exactness
-reference both cached paths are asserted against in
+attention) survives as ``slot_cached=False``, for any family — the
+token-exactness reference the slot pool is asserted against in
 tests/test_streaming_live.py and tests/test_paged_kv.py.
 """
 from __future__ import annotations
@@ -114,6 +114,15 @@ def copy_page(stages, dst, src):
     """Every layer's KV page ``src`` copied onto page ``dst``."""
     return jax.tree_util.tree_map(lambda x: x.at[:, dst].set(x[:, src]),
                                   stages)
+
+
+def _nbytes(tree) -> int:
+    return int(sum(x.nbytes for x in jax.tree_util.tree_leaves(tree)))
+
+
+def snapshot_kv_bytes(snap: dict) -> int:
+    """Bytes of a host snapshot's K/V (see ``StreamingDecoder.suspend``)."""
+    return _nbytes(snap["kv"])
 
 
 class SlotPool:
@@ -296,15 +305,17 @@ class PrefixIndex:
 class StreamingDecoder:
     """Greedy decoder over a membership-changing request batch.
 
-    ``slot_cached=True`` (default): persistent slot-pool decode, O(1) per
-    token.  ``slot_cached=False``: the full-forward reference path, O(S)
-    per token.  Both produce identical greedy tokens while sequences stay
-    within ``max_len`` (asserted in tests under membership churn).
+    ``slot_cached=True`` (default): persistent slot-pool decode over KV
+    pages, O(1) per token; it raises ``ValueError`` for a family whose
+    cache the pages cannot hold (``M.supports_paging``).
+    ``slot_cached=False``: the full-forward reference path, O(S) per
+    token, for any family.  Both produce identical greedy tokens while
+    sequences stay within ``max_len`` (asserted in tests under
+    membership churn).
 
-    ``paged=None`` turns the paged KV layout on automatically where the
-    model family supports it (see module docstring); ``paged=False``
-    forces the contiguous per-slot rings; ``paged=True`` on an
-    unsupported family raises.
+    ``paged`` accepts only ``None`` and ``True``: the pages are the one
+    KV layout.  It is kept for callers that still pass ``paged=True``
+    and goes once they stop.
 
     ``b_max`` pre-sizes the pool (typically the library's slot budget, so
     the decode step compiles exactly once); it is a sizing hint, not a
@@ -325,17 +336,20 @@ class StreamingDecoder:
         self.slot_cached = slot_cached
         self.max_len = max_len or prompt_len + 64
         self.strict_prompts = strict_prompts
-        if paged is None:
-            paged = slot_cached and M.supports_paging(cfg)
-        elif paged and not M.supports_paging(cfg):
+        if paged not in (None, True):
+            raise ValueError(f"paged={paged!r}: the KV pages are the "
+                             "decoder's only layout")
+        if slot_cached and not M.supports_paging(cfg):
             raise ValueError(
-                f"paged KV cache unsupported for {cfg.name}: "
-                "recurrent/MLA/cross-attn/int8/windowed caches keep the "
-                "contiguous layout")
-        self.paged = bool(paged and slot_cached)
+                f"{cfg.arch_id} ({cfg.family} family) keeps a cache the KV "
+                "pages cannot hold (recurrent, MLA, sliding-window, int8, "
+                "vision or cross-attention); the slot pool serves it once "
+                "the one page manager covers it (ROADMAP.md item 2.8); "
+                "slot_cached=False runs the full-forward reference")
         self.page_size = page_size
         self.max_pages = -(-self.max_len // page_size)
         self.pages: Optional[PagePool] = None
+        self.page_bytes = 0                       # per page, all layers
         self.prefix = PrefixIndex()
         self._table: Optional[np.ndarray] = None  # host page table mirror
         self._table_dirty = False
@@ -348,14 +362,11 @@ class StreamingDecoder:
 
         self._fwd = jax.jit(forward)
         self._decode = jax.jit(_named(M.decode_step, cfg))
-        self._prefill_slots = jax.jit(_named(
-            M.prefill_into_slots, cfg, max_len=self.max_len))
         self._prefill_pages = jax.jit(_named(M.prefill_into_pages, cfg))
         self._copy_page = jax.jit(copy_page)
         self._shapes: set = set()                 # compile-shape audit
         self.pool = SlotPool(b_max or 0)
         self._cache = None                        # device cache pytree
-        self.measured_slot_bytes = 0              # real per-slot footprint
         self.prefill_tokens_total = 0             # admission cost counter
         self.shared_tokens_total = 0              # prefix tokens reused
         # prefix-page retention budget (bytes of refcount-zero pages to
@@ -409,12 +420,11 @@ class StreamingDecoder:
 
     def finish(self, rid: int) -> List[int]:
         """Release ``rid``'s state (slot + page references); returns its
-        generated token ids.  Contiguous: the freed slot's stale K/V is
-        inert until the next tenant's admission prefill overwrites the
-        row.  Paged: every mapped page is decref'd (freed pages purge
-        their prefix-index entries) and the table row reset to trash."""
+        generated token ids.  Every mapped page is decref'd (freed pages
+        purge their prefix-index entries) and the table row reset to
+        trash."""
         slot = self.pool.release(rid)
-        if slot is not None and self.paged and self._table is not None:
+        if slot is not None:
             for p in self._table[slot]:
                 p = int(p)
                 if p != PagePool.TRASH and self.pages.decref(p):
@@ -430,54 +440,50 @@ class StreamingDecoder:
     def has_suspended(self, rid: int) -> bool:
         return rid in self._suspended
 
-    def suspend(self, rid: int) -> int:
-        """Spill ``rid``'s decode state HOST-side and release its device
-        footprint (slot + pages), so an interactive request can take the
-        slot.  The snapshot — token buffer, per-row position, and the
-        row's K/V bytes — lives in ``_suspended`` until :meth:`resume`
-        restores it bit-exactly, WITHOUT re-prefill.  Returns the
-        snapshot's KV byte size (0 if ``rid`` holds no slot)."""
+    def _snapshot(self, rid: int) -> Optional[dict]:
+        """Host copy of ``rid``'s decode state, or None when it holds no
+        bound row: its token buffer (``tokens``, ``prompt_end``,
+        ``truncated``), its row position (``pos``), the logical index of
+        each mapped page (``page_idx``) and those pages' K/V (``kv``,
+        leaves (L, len(page_idx), page_size, K, hd))."""
         slot = self.pool.slot_of.get(rid)
         if slot is None or rid not in self._tokens or self._cache is None:
-            return 0
-        snap: dict = {
+            return None
+        mapped = [(pi, int(p)) for pi, p in enumerate(self._table[slot])
+                  if int(p) != PagePool.TRASH]
+        idx = np.asarray([p for _pi, p in mapped], np.int32)
+        return {
             "tokens": list(self._tokens[rid]),
             "prompt_end": self._prompt_end[rid],
             "truncated": self.truncated.get(rid, False),
             "pos": int(np.asarray(self._cache["pos"])[slot]),
+            "page_idx": [pi for pi, _p in mapped],
+            "kv": jax.tree_util.tree_map(lambda x: np.asarray(x[:, idx]),
+                                         self._cache["stages"]),
         }
-        if self.paged:
-            mapped = [(pi, int(p)) for pi, p in enumerate(self._table[slot])
-                      if int(p) != PagePool.TRASH]
-            idx = np.asarray([p for _pi, p in mapped], np.int32)
-            host = jax.tree_util.tree_map(
-                lambda x: np.asarray(x[:, idx]), self._cache["stages"])
-            snap["page_idx"] = [pi for pi, _p in mapped]
-            snap["kv"] = host
-            for _pi, p in mapped:
-                if self.pages.decref(p):
-                    self.prefix.forget_page(p)
-            self._table[slot] = PagePool.TRASH
-            self._table_dirty = True
-        else:
-            snap["kv"] = jax.tree_util.tree_map(
-                lambda x: np.asarray(x[:, slot]), self._cache["stages"])
-        nbytes = int(sum(x.nbytes
-                         for x in jax.tree_util.tree_leaves(snap["kv"])))
-        self.pool.release(rid)
-        del self._tokens[rid]
-        del self._prompt_end[rid]
-        self.truncated.pop(rid, None)
+
+    def suspend(self, rid: int) -> int:
+        """Spill ``rid``'s decode state HOST-side and release its device
+        footprint (slot + pages), so an interactive request can take the
+        slot.  The snapshot (:meth:`_snapshot`) lives in ``_suspended``
+        until :meth:`resume` restores it bit-exactly, WITHOUT re-prefill.
+        Returns the snapshot's KV byte size (0 if ``rid`` holds no
+        slot)."""
+        snap = self._snapshot(rid)
+        if snap is None:
+            return 0
+        self.finish(rid)
         self._suspended[rid] = snap
+        nbytes = snapshot_kv_bytes(snap)
         self.kv_suspend_bytes_total += nbytes
         return nbytes
 
     def resume(self, rid: int) -> int:
         """Re-admit a suspended ``rid`` from its host snapshot: bind a
-        slot, scatter the saved K/V back (paged: onto freshly allocated
-        pages), restore the row position — NO prefill runs.  Greedy
-        decode then continues bit-exactly where it stopped.  Returns the
-        restored KV byte size."""
+        slot, scatter the saved K/V back onto freshly allocated pages,
+        restore the row position — NO prefill runs.  Greedy decode then
+        continues bit-exactly where it stopped.  Returns the restored KV
+        byte size."""
         snap = self._suspended.pop(rid)
         if self.pool.free == 0:
             self._grow(len(self.pool.slot_of) + 1)
@@ -487,24 +493,17 @@ class StreamingDecoder:
         self._tokens[rid] = snap["tokens"]
         self._prompt_end[rid] = snap["prompt_end"]
         self.truncated[rid] = snap["truncated"]
-        if self.paged:
-            pages = [self.pages.alloc() for _ in snap["page_idx"]]
-            self._table[slot] = PagePool.TRASH
-            for pi, p in zip(snap["page_idx"], pages):
-                self._table[slot, pi] = p
-            self._table_dirty = True
-            idx = np.asarray(pages, np.int32)
-            self._cache["stages"] = jax.tree_util.tree_map(
-                lambda big, small: big.at[:, idx].set(small),
-                self._cache["stages"], snap["kv"])
-            self._sync_table()
-        else:
-            self._cache["stages"] = jax.tree_util.tree_map(
-                lambda big, small: big.at[:, slot].set(small),
-                self._cache["stages"], snap["kv"])
+        pages = [self.pages.alloc() for _ in snap["page_idx"]]
+        self._table[slot] = PagePool.TRASH
+        self._table[slot, snap["page_idx"]] = pages
+        self._table_dirty = True
+        idx = np.asarray(pages, np.int32)
+        self._cache["stages"] = jax.tree_util.tree_map(
+            lambda big, small: big.at[:, idx].set(small),
+            self._cache["stages"], snap["kv"])
+        self._sync_table()
         self._cache["pos"] = self._cache["pos"].at[slot].set(snap["pos"])
-        nbytes = int(sum(x.nbytes
-                         for x in jax.tree_util.tree_leaves(snap["kv"])))
+        nbytes = snapshot_kv_bytes(snap)
         if rid in self._adopted:
             self._adopted.discard(rid)
             self.kv_adopt_bytes_total += nbytes
@@ -529,13 +528,11 @@ class StreamingDecoder:
         restores it WITHOUT re-prefill — decode continues bit-exactly
         from the prefill worker's state.  Restore bytes are accounted to
         ``kv_adopt_bytes_total`` (a handoff, not a preemption resume).
-        Both decoders must use the same KV layout (same recipe, so same
-        paged/contiguous choice and ``max_len``).  Returns the
-        snapshot's KV byte size."""
+        Both decoders must use the same page size and ``max_len`` (same
+        recipe).  Returns the snapshot's KV byte size."""
         self._suspended[rid] = snap
         self._adopted.add(rid)
-        return int(sum(x.nbytes
-                       for x in jax.tree_util.tree_leaves(snap["kv"])))
+        return snapshot_kv_bytes(snap)
 
     # -- crash safety: non-destructive KV checkpoint export -------------
     def checkpoint(self, rid: int) -> Optional[dict]:
@@ -547,27 +544,9 @@ class StreamingDecoder:
         token-exactly from the snapshot there, losing only the steps
         generated since the export.  Returns None when ``rid`` holds no
         bound slot (nothing to snapshot)."""
-        slot = self.pool.slot_of.get(rid)
-        if slot is None or rid not in self._tokens or self._cache is None:
-            return None
-        snap: dict = {
-            "tokens": list(self._tokens[rid]),
-            "prompt_end": self._prompt_end[rid],
-            "truncated": self.truncated.get(rid, False),
-            "pos": int(np.asarray(self._cache["pos"])[slot]),
-        }
-        if self.paged:
-            mapped = [(pi, int(p)) for pi, p in enumerate(self._table[slot])
-                      if int(p) != PagePool.TRASH]
-            idx = np.asarray([p for _pi, p in mapped], np.int32)
-            snap["page_idx"] = [pi for pi, _p in mapped]
-            snap["kv"] = jax.tree_util.tree_map(
-                lambda x: np.asarray(x[:, idx]), self._cache["stages"])
-        else:
-            snap["kv"] = jax.tree_util.tree_map(
-                lambda x: np.asarray(x[:, slot]), self._cache["stages"])
-        self.kv_ckpt_bytes_total += int(sum(
-            x.nbytes for x in jax.tree_util.tree_leaves(snap["kv"])))
+        snap = self._snapshot(rid)
+        if snap is not None:
+            self.kv_ckpt_bytes_total += snapshot_kv_bytes(snap)
         return snap
 
     # -- the step -------------------------------------------------------
@@ -600,41 +579,40 @@ class StreamingDecoder:
         return out
 
     def _fresh_cache(self, cap: int):
-        """Device cache for ``cap`` rows (+ host paging structures)."""
-        if not self.paged:
-            return M.cache_init(self.cfg, cap, self.max_len)
+        """Device cache for ``cap`` rows and its host page table; the
+        first call also makes the page pool, its retention budget in
+        pages from ``retain_bytes``."""
         n_pages = 1 + cap * self.max_pages        # +1: the trash page
+        cache = M.paged_cache_init(self.cfg, cap, n_pages, self.page_size,
+                                   self.max_pages)
         if self.pages is None:
-            self.pages = PagePool(n_pages)
+            self.page_bytes = _nbytes(cache["stages"]) // n_pages
+            retained = max(1, self.retain_bytes // self.page_bytes) \
+                if self.retain_bytes else 0
+            self.pages = PagePool(n_pages, retained_cap=retained)
             # retained pages purge their index entries on ACTUAL free
             self.pages.on_evict_retained = self.prefix.forget_page
         self._table = np.zeros((cap, self.max_pages), np.int32)
         self._table_dirty = False                 # fresh device table is 0 too
-        return M.paged_cache_init(self.cfg, cap, n_pages, self.page_size,
-                                  self.max_pages)
+        return cache
 
     def _sync_table(self) -> None:
-        if self.paged and self._table_dirty:
+        if self._table_dirty:
             with span("repro.decoder.table_sync"):
                 self._cache["table"] = jax.numpy.asarray(self._table)
             self._table_dirty = False
 
     @property
-    def page_bytes(self) -> int:
-        """Per-page KV bytes across all layers (0 until first admit)."""
-        if not self.paged or self._cache is None or self.pages is None:
-            return 0
-        total = sum(x.nbytes
-                    for x in jax.tree_util.tree_leaves(self._cache["stages"]))
-        return int(total // self.pages.n_pages)
+    def measured_slot_bytes(self) -> int:
+        """Per-slot cache footprint: the ``max_pages * page_bytes`` a row
+        can pin at worst (0 until the cache exists)."""
+        return self.max_pages * self.page_bytes
 
     @property
     def kv_bytes_in_use(self) -> int:
-        """Bytes actually pinned by live requests (paged: mapped pages
-        count ONCE however many rows share them)."""
-        if self.paged:
-            return self.pages.in_use * self.page_bytes if self.pages else 0
-        return self.measured_slot_bytes * len(self.pool)
+        """Bytes actually pinned by live requests (mapped pages count
+        ONCE however many rows share them)."""
+        return self.pages.in_use * self.page_bytes if self.pages else 0
 
     # -- paged page lifecycle -------------------------------------------
     def _bind_pages(self, rid: int) -> int:
@@ -721,10 +699,9 @@ class StreamingDecoder:
 
     def _decode_active(self, active: List[int]) -> Dict[int, int]:
         B = self.pool.capacity
-        if self.paged:
-            with span("repro.decoder.pages") as sp:
-                sp.set_metadata(cow=sum(self._ensure_writable(r)
-                                        for r in active))
+        with span("repro.decoder.pages") as sp:
+            sp.set_metadata(cow=sum(self._ensure_writable(r)
+                                    for r in active))
         toks = np.full((B, 1), PAD, dtype=np.int32)
         mask = np.zeros((B,), dtype=bool)
         for r in active:
@@ -743,35 +720,25 @@ class StreamingDecoder:
                                      for r in active])
 
     def _admit(self, fresh: List[int]) -> Dict[int, int]:
-        """Prefill for newly admitted rows, in one launch.  Padding rows
-        DUPLICATE row 0 (same tokens, same slot/pages), so the duplicate
-        scatter writes identical bytes and live rows stay untouched.
-
-        Paged: only each request's unshared TAIL is prefilled, laid out
-        one page per row (``_chunk_rows``), so a short tail never pads
-        to the longest prompt of the admission.  Contiguous: one row per
-        request, rows → pow2, tokens → multiple of 8."""
+        """Prefill for newly admitted rows, in one launch.  Only each
+        request's unshared TAIL is prefilled, laid out one page per row
+        (``_chunk_rows``), so a short tail never pads to the longest
+        prompt of the admission.  Padding rows DUPLICATE row 0 (same
+        tokens, same slot and base), so the duplicate scatter writes
+        identical bytes and live rows stay untouched."""
         slots = [self.pool.bind(r) for r in fresh]
-        if self.paged:
-            with span("repro.decoder.pages"):
-                bases = [self._bind_pages(r) for r in fresh]
-            seqs, rows, lasts = self._chunk_rows(fresh, slots, bases)
-            n_real = len(seqs)
-            S = self.page_size
-            Bn = _next_pow2(n_real)
-            if Bn > self.pool.capacity:
-                Bn = _round_up(n_real, self.pool.capacity)
-        else:
-            seqs = [self._tokens[r] for r in fresh]
-            rows = [(s, 0) for s in slots]
-            lasts = list(range(len(fresh)))
-            n_real = len(fresh)
-            S = min(_round_up(max(len(s) for s in seqs), 8), self.max_len)
-            Bn = _next_pow2(n_real)
-        lens = [min(len(s), S) for s in seqs]     # exactness holds ≤ max_len
+        with span("repro.decoder.pages"):
+            bases = [self._bind_pages(r) for r in fresh]
+        seqs, rows, lasts = self._chunk_rows(fresh, slots, bases)
+        n_real = len(seqs)
+        S = self.page_size
+        Bn = _next_pow2(n_real)
+        if Bn > self.pool.capacity:
+            Bn = _round_up(n_real, self.pool.capacity)
+        lens = [len(s) for s in seqs]
         arr = np.full((Bn, S), PAD, dtype=np.int32)
         for i, s in enumerate(seqs):
-            arr[i, :lens[i]] = s[:lens[i]]
+            arr[i, :lens[i]] = s
         arr[n_real:] = arr[0]
         pad = Bn - n_real
         slot_arr = np.asarray([s for s, _b in rows] + [rows[0][0]] * pad,
@@ -781,32 +748,13 @@ class StreamingDecoder:
         len_arr = np.asarray(lens + [lens[0]] * pad, np.int32)
         self.prefill_tokens_total += sum(lens)
         self._sync_table()
-        stats = dict(rows=len(fresh), padded_rows=Bn, tokens=sum(lens),
-                     padded_tokens=Bn * S)
-        if self.paged:
-            stats["chunks"] = n_real
-        with self._launch("prefill_into_pages" if self.paged
-                          else "prefill_into_slots",
-                          ("prefill", Bn, S, self.pool.capacity), **stats):
-            if self.paged:
-                logits, self._cache = self._prefill_pages(
-                    self.params, {"tokens": arr}, self._cache, slot_arr,
-                    base_arr, len_arr)
-            else:
-                logits, self._cache = self._prefill_slots(
-                    self.params, {"tokens": arr}, self._cache, slot_arr,
-                    len_arr)
-        if not self.measured_slot_bytes:
-            if self.paged:
-                self.measured_slot_bytes = self.page_bytes * self.max_pages
-                if self.retain_bytes and self.page_bytes:
-                    # byte budget -> page count, now that pages have a size
-                    self.pages.retained_cap = max(
-                        1, self.retain_bytes // self.page_bytes)
-            else:
-                total = sum(x.nbytes
-                            for x in jax.tree_util.tree_leaves(self._cache))
-                self.measured_slot_bytes = int(total // self.pool.capacity)
+        with self._launch("prefill_into_pages",
+                          ("prefill", Bn, S, self.pool.capacity),
+                          rows=len(fresh), padded_rows=Bn, tokens=sum(lens),
+                          padded_tokens=Bn * S, chunks=n_real):
+            logits, self._cache = self._prefill_pages(
+                self.params, {"tokens": arr}, self._cache, slot_arr,
+                base_arr, len_arr)
         return self._sample(logits, [(r, (i, 0))
                                      for r, i in zip(fresh, lasts)])
 
@@ -832,7 +780,7 @@ class StreamingDecoder:
         copied across GENERICALLY — every leaf of the old cache pytree is
         prefix-sliced into the freshly initialised one (and cache keys
         the initialiser doesn't know about are carried verbatim), so
-        growth is invisible to in-flight requests whatever the layout."""
+        growth is invisible to in-flight requests."""
         cap = max(self.pool.capacity, 1)
         while cap < needed:
             cap *= 2
@@ -859,15 +807,12 @@ class StreamingDecoder:
                 merged.setdefault(key, val)
             new_cache = merged
         self._cache = new_cache
-        if self.paged:
-            self.pages.grow(1 + cap * self.max_pages)
-            if old_table is not None:
-                self._table[:old_cap] = old_table
-            self._table_dirty = True
+        self.pages.grow(1 + cap * self.max_pages)
+        if old_table is not None:
+            self._table[:old_cap] = old_table
+        self._table_dirty = True
         self.pool.grow(cap)
-        self.measured_slot_bytes = 0              # re-measure at new B
-        if self.paged:
-            self._sync_table()
+        self._sync_table()
 
     def _step_full(self, rids: List[int]) -> Dict[int, int]:
         """Reference path: full forward over prompt+generated each step."""
@@ -895,8 +840,7 @@ class StreamingDecoder:
 
 def make_pff_step_fn(prompt_len: int = PROMPT_LEN, *,
                      slot_cached: bool = True,
-                     max_len: Optional[int] = None,
-                     paged: Optional[bool] = None):
+                     max_len: Optional[int] = None):
     """Step function for :class:`~repro.cluster.LiveExecutor.step_fns`.
 
     Lazily builds a :class:`StreamingDecoder` inside the library's
@@ -922,8 +866,7 @@ def make_pff_step_fn(prompt_len: int = PROMPT_LEN, *,
             dec = StreamingDecoder(engine.cfg, engine.params,
                                    ci["tokenizer"], ci["template"],
                                    prompt_len=prompt_len,
-                                   slot_cached=slot_cached, max_len=max_len,
-                                   paged=paged)
+                                   slot_cached=slot_cached, max_len=max_len)
             payloads["_stream_decoder"] = dec
         # shipped-in KV snapshots parked before this decoder existed (or
         # between steps): take ownership so has_suspended resumes them

@@ -343,9 +343,11 @@ def cache_init(cfg, batch: int, max_len: int):
 def supports_paging(cfg) -> bool:
     """Whether this model family can run the PAGED decode cache.
 
-    Paging covers the GQA ring-KV block types; recurrent state (SSM/xLSTM/
-    hymba), MLA latents, cross-attention K/V, int8-quantised caches and
-    sliding-window rings keep the contiguous per-slot layout."""
+    Paging covers the GQA ring-KV block types.  Recurrent state (SSM/
+    xLSTM/hymba), MLA latents, cross-attention K/V, int8-quantised caches
+    and sliding-window rings have only the contiguous ``cache_init``
+    layout, which the lock-step engine uses; the streaming slot pool
+    refuses them."""
     return (all(t in ("dense", "moe") for t in layer_types(cfg))
             and cfg.kv_cache_dtype != "int8"
             and not cfg.attn_window
@@ -414,42 +416,6 @@ def decode_step(cfg, params, cache, tokens, step_mask=None):
         jnp.where(jnp.asarray(step_mask), pos + 1, pos)
     # preserve any additional cache entries (the page table) verbatim
     return logits, {**cache, "stages": new_caches, "pos": new_pos}
-
-
-def prefill_into_slots(cfg, params, batch, cache, slots, lengths,
-                       max_len: int):
-    """Prompt-only prefill for NEWLY ADMITTED rows of a persistent slot pool.
-
-    Runs the prefill forward over ``batch`` (Bn rows, right-padded to a
-    bucketed S) and scatters the resulting per-layer K/V rows plus per-row
-    positions into the SHARED decode cache at batch indices ``slots`` (Bn,)
-    — live rows (every other slot) are untouched, so admission churn never
-    re-pays prefill for requests already in flight.
-
-    ``lengths`` (Bn,) are the true (unpadded) token counts; the returned
-    logits are gathered at each row's own last real position.  ``max_len``
-    MUST equal the max_len the shared cache was built with (same ring T).
-    Returns (next-token logits (Bn,1,V), updated cache).
-    """
-    x, positions, extras, n_prefix = embed_batch(cfg, params, batch)
-    x, caches = _run_stages_prefill(cfg, params, x, positions, extras,
-                                    max_len)
-    lengths = jnp.asarray(lengths, jnp.int32)
-    Bn, _S, D = x.shape
-    last = n_prefix + lengths - 1                       # (Bn,)
-    h = jnp.take_along_axis(
-        x, jnp.broadcast_to(last[:, None, None], (Bn, 1, D)), axis=1)
-    h = rmsnorm(h, params["final_norm"], cfg.norm_eps)
-    logits = _unembed(cfg, params, h)
-
-    # stage-cache leaves are (L, Bn, ...): scatter rows at batch axis 1.
-    def scatter(big, small):
-        return big.at[:, slots].set(small.astype(big.dtype))
-
-    new_stages = jax.tree_util.tree_map(scatter, cache["stages"], caches)
-    new_pos = cache["pos"].at[slots].set(n_prefix + lengths)
-    # preserve any additional cache entries (sampling state etc.) verbatim
-    return logits, {**cache, "stages": new_stages, "pos": new_pos}
 
 
 def prefill_into_pages(cfg, params, batch, cache, slots, base, lengths):

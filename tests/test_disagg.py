@@ -333,7 +333,7 @@ class TestExpirableProgress:
 
 
 # ---------------------------------------------------------------------------
-# Live: shipped KV decodes token-exact (both layouts)
+# Live: shipped KV decodes token-exact
 # ---------------------------------------------------------------------------
 
 @pytest.fixture(scope="module")
@@ -347,7 +347,7 @@ def live_setup():
 
 
 class TestLiveShippedKV:
-    def _run(self, claims, recipe, *, disaggregate, paged):
+    def _run(self, claims, recipe, *, disaggregate):
         from repro.inference import make_pff_step_fn
         sched = Scheduler(disaggregate=disaggregate)
         app = Application(sched)
@@ -357,17 +357,14 @@ class TestLiveShippedKV:
         for c in claims:
             app.submit(key, prompt_units=2, decode_steps=5, payload=c)
         ex = LiveExecutor(sched,
-                          step_fns={key: make_pff_step_fn(paged=paged)})
+                          step_fns={key: make_pff_step_fn()})
         ex.run()
         return [ex.results[r.request_id] for r in app.requests], sched
 
-    @pytest.mark.parametrize("paged", [False, True],
-                             ids=["contiguous", "paged"])
-    def test_shipped_decode_matches_colocated(self, live_setup, paged):
+    def test_shipped_decode_matches_colocated(self, live_setup):
         cfg, claims, recipe = live_setup
-        base, _ = self._run(claims, recipe, disaggregate=False, paged=paged)
-        dis, sched = self._run(claims, recipe, disaggregate=True,
-                               paged=paged)
+        base, _ = self._run(claims, recipe, disaggregate=False)
+        dis, sched = self._run(claims, recipe, disaggregate=True)
         assert base == dis
         assert sched.kv_ships > 0
         assert sched.prefills_done == len(claims)
@@ -380,7 +377,7 @@ class TestLiveShippedKV:
         under its own counter, so preemption resume accounting stays
         exact."""
         cfg, claims, recipe = live_setup
-        _, sched = self._run(claims, recipe, disaggregate=True, paged=False)
+        _, sched = self._run(claims, recipe, disaggregate=True)
         decs = [lib.context.payloads.get("_stream_decoder")
                 for w in sched.workers.values()
                 for lib in w.libraries.values()]

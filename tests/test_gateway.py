@@ -309,17 +309,14 @@ def live_setup():
     return cfg, M.init_params(cfg, jax.random.PRNGKey(0))
 
 
-@pytest.mark.parametrize("paged", [False, True])
-def test_suspend_resume_tokens_bit_exact(live_setup, paged):
+def test_suspend_resume_tokens_bit_exact(live_setup):
     import numpy as np
     from repro.inference import StreamingDecoder
     cfg, params = live_setup
     rng = np.random.default_rng(3)
     prompts = {r: list(rng.integers(4, cfg.vocab_size, 10 + 2 * r))
                for r in range(3)}
-    kw = dict(max_len=48, paged=paged)
-    if paged:
-        kw["page_size"] = 8
+    kw = dict(max_len=48, page_size=8)
 
     def decode(suspend_at):
         dec = StreamingDecoder(cfg, params, None, None, **kw)
@@ -339,12 +336,11 @@ def test_suspend_resume_tokens_bit_exact(live_setup, paged):
         for r in prompts:
             dec.finish(r)
         assert dec.pool.free == dec.pool.capacity, "slot leak"
-        if paged:
-            assert dec.pages.in_use == 0, "page leak"
+        assert dec.pages.in_use == 0, "page leak"
         assert not dec._suspended
         return outs[0]
 
     reference = decode(None)
     for point in (1, 5):
         assert decode(point) == reference, \
-            f"tokens diverged after suspend at step {point} (paged={paged})"
+            f"tokens diverged after suspend at step {point}"

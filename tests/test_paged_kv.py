@@ -11,8 +11,12 @@ The invariants the paged layout must hold under live churn:
 * admission maps an indexed prefix by reference: zero prefill tokens and
   zero new pages for the shared span;
 * the decode step still compiles ONCE per pool capacity with paging on;
-* paged greedy tokens equal the contiguous slot pool's and the
-  full-forward reference's under membership churn.
+* paged greedy tokens equal the full-forward reference's under
+  membership churn, and the engine's contiguous ring's across a ring
+  wrap;
+* a checkpoint leaves its request decoding, and its snapshot resumes
+  token-exactly in another decoder;
+* the slot pool refuses every cache the pages cannot hold.
 
 Plus the three bugfix regressions riding along: over-length prompts
 raise or set the ``truncated`` flag (never a silent clip), cache growth
@@ -20,6 +24,9 @@ carries UNKNOWN cache keys (a layout the grower doesn't know about must
 survive ``_grow``), and ``make_pff_step_fn`` frees decoder state for
 requests the scheduler pulled out of the batch mid-flight.
 """
+import dataclasses
+import functools
+
 import numpy as np
 import pytest
 
@@ -27,7 +34,8 @@ import jax
 
 from repro.configs import get_smoke_config
 from repro.inference.streaming import (PagePool, PrefixIndex,
-                                       StreamingDecoder, make_pff_step_fn)
+                                       StreamingDecoder, make_pff_step_fn,
+                                       snapshot_kv_bytes)
 from repro.models import model as M
 
 
@@ -67,6 +75,20 @@ def _run(dec, prompts, script):
             out.setdefault(r, []).append(t)
         for r in fins:
             dec.finish(r)
+    return out
+
+
+def _ring_greedy(cfg, params, prompt, n, max_len):
+    """``n`` greedy tokens of one prompt through the engine's contiguous
+    ring (``M.prefill``, then ``M.decode_step``): a reference that shares
+    no code with the page pool."""
+    step = jax.jit(functools.partial(M.decode_step, cfg))
+    logits, cache = M.prefill(
+        cfg, params, {"tokens": np.asarray([prompt], np.int32)}, max_len)
+    out = []
+    for _ in range(n):
+        out.append(int(np.argmax(np.asarray(logits)[0, -1])))
+        logits, cache = step(params, cache, np.asarray([[out[-1]]], np.int32))
     return out
 
 
@@ -145,14 +167,13 @@ class TestPagedDecoder:
         cfg, params = setup
         rng = np.random.default_rng(3)
         prompts = _prompts(rng, cfg, 8)
-        paged = _run(_mk(cfg, params, paged=True), prompts, CHURN)
-        slot = _run(_mk(cfg, params, paged=False), prompts, CHURN)
+        paged = _run(_mk(cfg, params), prompts, CHURN)
         full = _run(_mk(cfg, params, slot_cached=False), prompts, CHURN)
-        assert paged == slot == full
+        assert paged == full
 
     def test_all_pages_freed_and_index_purged_after_churn(self, setup):
         cfg, params = setup
-        dec = _mk(cfg, params, paged=True)
+        dec = _mk(cfg, params)
         _run(dec, _prompts(np.random.default_rng(4), cfg, 8), CHURN)
         assert dec.pages.in_use == 0
         assert dec.pages.free == dec.pages.n_pages - 1
@@ -165,7 +186,7 @@ class TestPagedDecoder:
         cfg, params = setup
         rng = np.random.default_rng(5)
         prompts = _prompts(rng, cfg, 4)
-        dec = _mk(cfg, params, paged=True)
+        dec = _mk(cfg, params)
         dec.ensure_tokens(0, prompts[0])
         dec.step([0])
         t0, p0 = dec.prefill_tokens_total, dec.pages.in_use
@@ -185,7 +206,7 @@ class TestPagedDecoder:
         cfg, params = setup
         rng = np.random.default_rng(6)
         prompts = _prompts(rng, cfg, 4)
-        dec = _mk(cfg, params, paged=True)
+        dec = _mk(cfg, params)
         ref = _mk(cfg, params, slot_cached=False)
         script = [([0], []), ([0, 2], []), ([0, 2], [0]),
                   ([2], []), ([2], []), ([2], [2])]
@@ -197,22 +218,24 @@ class TestPagedDecoder:
     def test_copy_on_write_on_ring_wrap(self, setup):
         """Two tenants share a prefix; both generate past the ring length
         so their writes WRAP into the shared pages.  Each must copy first
-        — tokens stay equal to the contiguous slot pool's (same ring T),
-        and while both are live the shared pages get un-shared."""
+        — tokens stay equal to each tenant's own run through the engine's
+        contiguous ring of the same T, and while both are live the shared
+        pages get un-shared."""
         cfg, params = setup
         rng = np.random.default_rng(7)
         shared = list(rng.integers(4, cfg.vocab_size, 16))
         prompts = {0: shared + list(rng.integers(4, cfg.vocab_size, 5)),
                    1: shared + list(rng.integers(4, cfg.vocab_size, 3))}
-        # T = 24 for both layouts: wrap after ~8 generated tokens
-        paged = _mk(cfg, params, paged=True, max_len=24)
-        slot = _mk(cfg, params, paged=False, max_len=24)
+        # T = 24 for the pages and the ring: wrap after ~8 generated tokens
+        paged = _mk(cfg, params, max_len=24)
         script = [([0], []), ([0, 1], [])] + [([0, 1], [])] * 12
         got = _run(paged, prompts, script)
         n_shared_mid = len([p for p in range(1, paged.pages.n_pages)
                             if paged.pages.refcount(p) > 1])
         assert n_shared_mid == 0, "wrap must have COW'd the shared pages"
-        assert got == _run(slot, prompts, script)
+        assert [len(got[r]) for r in (0, 1)] == [14, 13]
+        assert got == {r: _ring_greedy(cfg, params, prompts[r], len(got[r]),
+                                       max_len=24) for r in (0, 1)}
         for r in (0, 1):
             paged.finish(r)
         assert paged.pages.in_use == 0
@@ -221,7 +244,7 @@ class TestPagedDecoder:
         """Recompile audit with paging ON: whatever the admissions, COWs
         and table rewrites, decode has ONE compiled shape per capacity."""
         cfg, params = setup
-        dec = _mk(cfg, params, paged=True, b_max=4)
+        dec = _mk(cfg, params, b_max=4)
         _run(dec, _prompts(np.random.default_rng(8), cfg, 8), CHURN)
         decode_shapes = [s for s in dec._shapes if s[0] == "decode"]
         assert decode_shapes == [("decode", 4)]
@@ -256,7 +279,7 @@ class TestPagedDecoder:
                    for r, (n, m) in enumerate(spec)}
         rids = list(prompts)
         script = [(rids, [])] * 9
-        dec = _mk(cfg, params, paged=True)
+        dec = _mk(cfg, params)
         got = _run(dec, prompts, script[:1])
         assert ("prefill", rows, 8, cap) in dec._shapes
         assert dec.pool.capacity == cap
@@ -271,27 +294,92 @@ class TestPagedDecoder:
         assert got == ref
 
     def test_measured_slot_bytes_is_page_budget(self, setup):
+        """The per-slot bytes follow from the pool's shapes: 0 before the
+        cache exists, then the worst case ``max_pages * page_bytes``; the
+        retention budget is set in pages when the pool is made."""
         cfg, params = setup
-        dec = _mk(cfg, params, paged=True)
+        dec = _mk(cfg, params)
+        assert dec.measured_slot_bytes == 0 and dec.kv_bytes_in_use == 0
         dec.ensure_tokens(0, list(range(4, 24)))
         dec.step([0])
         assert dec.page_bytes > 0
         assert dec.measured_slot_bytes == dec.max_pages * dec.page_bytes
         assert dec.kv_bytes_in_use == dec.pages.in_use * dec.page_bytes
+        assert dec.pages.retained_cap == 0
+        kept = _mk(cfg, params, retain_bytes=3 * dec.page_bytes + 1)
+        kept.ensure_tokens(0, list(range(4, 24)))
+        kept.step([0])
+        assert kept.pages.retained_cap == 3
+        assert kept.measured_slot_bytes == dec.measured_slot_bytes
+
+    def test_checkpoint_keeps_decoding_and_resumes_elsewhere(self, setup):
+        """``checkpoint`` copies a row out without touching it: the source
+        decodes on with its page refcounts unchanged, and the snapshot,
+        adopted and resumed by a second decoder, continues with the
+        uninterrupted run's tokens.  Rid 2 shares rid 0's prefix pages,
+        so the snapshot holds shared pages too."""
+        cfg, params = setup
+        prompts = _prompts(np.random.default_rng(12), cfg, 4)
+        steps, at = 10, 4
+        ref = _mk(cfg, params)
+        want = _run(ref, prompts, [([0, 2], [])] * steps)[2]
+        src = _mk(cfg, params)
+        got = _run(src, prompts, [([0, 2], [])] * at)[2]
+        refs = {p: src.pages.refcount(p) for p in range(1, src.pages.n_pages)}
+        snap = src.checkpoint(2)
+        assert src.checkpoint(9) is None           # no bound row
+        assert src.pool.slot_of.get(2) is not None
+        assert refs == {p: src.pages.refcount(p)
+                        for p in range(1, src.pages.n_pages)}
+        assert snap["pos"] == len(prompts[2]) + at - 1
+        assert src.kv_ckpt_bytes_total == snapshot_kv_bytes(snap) > 0
+        rest = _run(src, prompts, [([0, 2], [])] * (steps - at))[2]
+        assert got + rest == want                  # the source decodes on
+        dst = _mk(cfg, params)
+        assert dst.adopt(2, snap) == snapshot_kv_bytes(snap)
+        assert dst.resume(2) == snapshot_kv_bytes(snap)
+        assert dst.kv_adopt_bytes_total == snapshot_kv_bytes(snap)
+        assert dst.kv_resume_bytes_total == 0
+        resumed = [dst.step([2])[2] for _ in range(steps - at)]
+        assert got + resumed == want
+        assert dst.finish(2) == want
+        assert dst.pages.in_use == 0
+
+    @pytest.mark.parametrize("arch, kv_cache_dtype, paged", [
+        ("llava-next-34b", "", None), ("hymba-1.5b", "", None),
+        ("xlstm-350m", "", None), ("whisper-small", "", None),
+        ("deepseek-v3-671b", "", None), ("smollm2-1.7b", "int8", None),
+        ("smollm2-1.7b", "", False)],
+        ids=["vlm", "hybrid", "ssm", "audio", "mla", "int8", "paged_false"])
+    def test_refuses_caches_the_pages_cannot_hold(self, arch,
+                                                   kv_cache_dtype, paged):
+        """The slot pool refuses, by name, every configuration whose cache
+        the pages cannot hold, and ``paged=False``; the full-forward
+        reference still takes every family."""
+        cfg = dataclasses.replace(get_smoke_config(arch),
+                                  kv_cache_dtype=kv_cache_dtype)
+        with pytest.raises(ValueError) as err:
+            StreamingDecoder(cfg, None, None, None, paged=paged)
+        if paged is None:
+            assert not M.supports_paging(cfg)
+            assert cfg.arch_id in str(err.value)
+            assert "2.8" in str(err.value)
+            StreamingDecoder(cfg, None, None, None, slot_cached=False)
+        else:
+            assert "paged=False" in str(err.value)
 
 
 class TestBugfixRegressions:
     def test_overlong_prompt_strict_raises(self, setup):
         cfg, params = setup
-        dec = _mk(cfg, params, paged=True, strict_prompts=True)
+        dec = _mk(cfg, params, strict_prompts=True)
         with pytest.raises(ValueError, match="caps prompts"):
             dec.ensure_tokens(0, list(range(4, 4 + 80)))
         assert 0 not in dec._tokens                # nothing half-admitted
 
-    @pytest.mark.parametrize("paged", [False, True])
-    def test_overlong_prompt_sets_truncated_flag(self, setup, paged):
+    def test_overlong_prompt_sets_truncated_flag(self, setup):
         cfg, params = setup
-        dec = _mk(cfg, params, paged=paged)
+        dec = _mk(cfg, params)
         dec.ensure_tokens(0, list(range(4, 4 + 80)))
         dec.ensure_tokens(1, list(range(4, 24)))
         assert dec.truncated[0] is True
@@ -301,8 +389,7 @@ class TestBugfixRegressions:
         dec.finish(0)
         assert 0 not in dec.truncated              # state fully released
 
-    @pytest.mark.parametrize("paged", [False, True])
-    def test_grow_preserves_unknown_cache_keys(self, setup, paged):
+    def test_grow_preserves_unknown_cache_keys(self, setup):
         """_grow must rebuild the cache GENERICALLY: keys the initialiser
         does not produce (here a fake sampling-state leaf) ride across
         growth with their prefix contents intact — and live requests
@@ -310,7 +397,7 @@ class TestBugfixRegressions:
         cfg, params = setup
         rng = np.random.default_rng(9)
         prompts = _prompts(rng, cfg, 6)
-        dec = _mk(cfg, params, paged=paged, b_max=2)
+        dec = _mk(cfg, params, b_max=2)
         ref = _mk(cfg, params, slot_cached=False)
         script = [([0, 1], [])] * 2
         got = _run(dec, prompts, script)
@@ -366,15 +453,13 @@ class TestBugfixRegressions:
         live = {reqs[1].request_id, reqs[2].request_id}
         assert set(dec.active_rids()) == live
         assert set(dec.pool.slot_of) == live
-        if dec.paged:                              # rid 0's pages came back
-            held = {int(p) for row in dec._table for p in row if p}
-            assert dec.pages.in_use == len(held)
+        held = {int(p) for row in dec._table for p in row if p}
+        assert dec.pages.in_use == len(held)      # rid 0's pages came back
         # drain everyone: step_fn's own finish path frees the rest
         for _ in range(8):
             run([reqs[1], reqs[2]])
         assert dec.active_rids() == []
-        if dec.paged:
-            assert dec.pages.in_use == 0
+        assert dec.pages.in_use == 0
 
     def test_truncated_flag_reaches_request(self, setup):
         """make_pff_step_fn surfaces the decoder's clip onto the Request,

@@ -130,18 +130,16 @@ class TestSlotPoolDecoding:
                     live.remove(rid)
         return toks
 
-    @pytest.mark.parametrize("paged", [False, True])
-    def test_churn_token_exact_and_slot_reuse_no_leak(self, setup, paged):
+    def test_churn_token_exact_and_slot_reuse_no_leak(self, setup):
         """10 requests through a ≤4-slot pool, membership changing at
         every step: every slot is re-tenanted at least once, and the
         slot-cached tokens must equal the full-forward reference's —
         a freed slot's stale K/V leaking into its next tenant would
-        diverge immediately.  Runs both KV layouts: contiguous per-slot
-        rings and refcounted pages (the rendered claim prompts share the
-        template preamble, so the paged run also exercises prefix reuse
-        under churn)."""
+        diverge immediately.  The rendered claim prompts share the
+        template preamble, so this also exercises prefix reuse under
+        churn."""
         cfg, claims, _, payloads = setup
-        slot = self._mk(payloads, paged=paged)
+        slot = self._mk(payloads)
         full = self._mk(payloads, slot_cached=False)
         budget = {rid: 3 + (rid % 4) for rid in range(10)}
         got = self._churn(slot, claims, budget)
@@ -172,14 +170,17 @@ class TestSlotPoolDecoding:
         assert dec.shape_buckets == 2
 
     def test_b_max_presized_pool(self, setup):
-        """A pool pre-sized to the library's slot budget never grows."""
+        """A pool pre-sized to the library's slot budget never grows; its
+        per-slot bytes are the page budget from the moment the cache
+        exists."""
         cfg, claims, _, payloads = setup
         dec = self._mk(payloads, b_max=4)
+        assert dec.measured_slot_bytes == 0
         for rid in range(4):
             dec.ensure(rid, claims[rid])
         dec.step(list(range(4)))
         assert dec.pool.capacity == 4
-        assert dec.measured_slot_bytes > 0
+        assert dec.measured_slot_bytes == dec.max_pages * dec.page_bytes > 0
 
 
 class TestLiveStreamServing:

@@ -69,11 +69,11 @@ def test_the_cluster_layer_imports_no_jax():
 
 def test_jitted_programs_carry_their_names(setup):
     cfg, params = setup
-    dec = _mk(cfg, params, paged=True)
-    names = {f.__name__ for f in (dec._fwd, dec._decode, dec._prefill_slots,
-                                  dec._prefill_pages, dec._copy_page)}
-    assert names == {"forward", "decode_step", "prefill_into_slots",
-                     "prefill_into_pages", "copy_page"}
+    dec = _mk(cfg, params)
+    names = {f.__name__ for f in (dec._fwd, dec._decode, dec._prefill_pages,
+                                  dec._copy_page)}
+    assert names == {"forward", "decode_step", "prefill_into_pages",
+                     "copy_page"}
     dec.ensure_tokens(0, list(range(4, 14)))
     dec.step([0])
     toks = np.zeros((dec.pool.capacity, 1), np.int32)
@@ -91,7 +91,7 @@ def test_paged_launches_count_rows_tokens_pages_and_new_shapes(
     shared = list(rng.integers(4, cfg.vocab_size, 16))
     prompts = {0: shared + list(rng.integers(4, cfg.vocab_size, 5)),
                1: shared + list(rng.integers(4, cfg.vocab_size, 3))}
-    dec = _mk(cfg, params, paged=True)
+    dec = _mk(cfg, params)
     marks = []
 
     def drive():
@@ -138,12 +138,11 @@ def test_paged_launches_count_rows_tokens_pages_and_new_shapes(
     assert cow == 1
 
 
-@pytest.mark.parametrize("kw, program", [
-    ({"paged": False}, "prefill_into_slots"),
-    ({"slot_cached": False}, "forward")])
-def test_unpaged_launches_name_their_program(setup, tmp_path, kw, program):
+def test_unpaged_launches_name_their_program(setup, tmp_path):
+    """The full-forward reference launches ``forward`` and holds no
+    pages."""
     cfg, params = setup
-    dec = _mk(cfg, params, **kw)
+    dec = _mk(cfg, params, slot_cached=False)
 
     def drive():
         dec.ensure_tokens(0, list(range(4, 14)))
@@ -151,7 +150,7 @@ def test_unpaged_launches_name_their_program(setup, tmp_path, kw, program):
         dec.step([0])
 
     runs = launches(traced(tmp_path, drive))
-    assert runs[0]["program"] == program
+    assert runs[0]["program"] == "forward"
     assert runs[0]["tokens"] == 10 and runs[0]["new_shape"] == 1
     assert all("pages_in_use" not in r and "chunks" not in r for r in runs)
 
@@ -162,7 +161,7 @@ def test_decode_launches_name_the_kernel_shape(tmp_path, arch):
     q-heads per KV head and head dim; an admission carries none of them."""
     cfg = get_smoke_config(arch)
     params = M.init_params(cfg, jax.random.PRNGKey(0))
-    dec = _mk(cfg, params, paged=True)
+    dec = _mk(cfg, params)
 
     def drive():
         dec.ensure_tokens(0, list(range(4, 14)))
